@@ -31,14 +31,15 @@ def main() -> None:
                                atol=1e-4)
     print(f"fused-group execution == monolithic ✓ (logits {y_mono.shape})")
 
-    # stem conv through the Pallas fused kernel (interpret on CPU)
+    # stem conv through the Pallas fused kernel, in interpret mode: this
+    # example runs on the CPU (chip_smoke.py runs the compiled kernel)
     bn = params["bn1"]
     inv = jax.lax.rsqrt(bn["var"] + 1e-5)
     scale = (bn["scale"] * inv).astype(x.dtype)
     shift = (bn["bias"] - bn["mean"] * inv * bn["scale"]).astype(x.dtype)
     y_kernel = fused_conv_kernel(x, params["conv1"], scale, shift,
                                  stride=2, padding=3, relu=True,
-                                 tile_h=4, tile_w=4, cout_block=64)
+                                 tile_h=4, cout_block=64, interpret=True)
     ref = jax.nn.relu(
         (jax.lax.conv_general_dilated(
             x, params["conv1"], (2, 2), [(3, 3), (3, 3)],

@@ -30,20 +30,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                    # jax >= 0.6 top-level API
-    from jax import shard_map as _shard_map
-except ImportError:                     # pragma: no cover - jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _axis_size(axis_name) -> int:
-    """Mesh-axis size inside a shard_map body; ``jax.lax.axis_size`` only
-    exists on newer jax, ``psum(1, axis)`` is the portable spelling."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def group_halo_rows(group_graph, tiles: int) -> int:
@@ -66,7 +54,7 @@ def exchange_halo(x: jnp.ndarray, halo_up: int, halo_down: int,
     ``halo_up`` rows from the previous device and ``halo_down`` rows from
     the next (zero rows at the boundary devices — conv padding semantics).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     parts = []
     if halo_up:
@@ -109,8 +97,8 @@ def run_fused_group(group_fn: Callable[[jnp.ndarray], jnp.ndarray],
         return _crop_valid(y, shrink, shrink)
 
     spec_in = P(None, axis, None, None)
-    return _shard_map(local, mesh=mesh, in_specs=(spec_in,),
-                      out_specs=spec_in)(x)
+    return shard_map(local, mesh=mesh, in_specs=(spec_in,),
+                     out_specs=spec_in)(x)
 
 
 def run_fused_group_exact(layer_fns, x: jnp.ndarray, mesh: Mesh, *,
@@ -123,7 +111,7 @@ def run_fused_group_exact(layer_fns, x: jnp.ndarray, mesh: Mesh, *,
     H = x.shape[1]
 
     def local(xs: jnp.ndarray) -> jnp.ndarray:
-        n = _axis_size(axis)
+        n = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
         shard = H // n
         ext = exchange_halo(xs, halo, halo, axis)
@@ -136,5 +124,5 @@ def run_fused_group_exact(layer_fns, x: jnp.ndarray, mesh: Mesh, *,
         return y[:, halo:-halo] if halo else y
 
     spec_in = P(None, axis, None, None)
-    return _shard_map(local, mesh=mesh, in_specs=(spec_in,),
-                      out_specs=spec_in)(x)
+    return shard_map(local, mesh=mesh, in_specs=(spec_in,),
+                     out_specs=spec_in)(x)

@@ -24,22 +24,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                    # jax >= 0.6 top-level API
-    from jax import shard_map as _shard_map
-except ImportError:                     # pragma: no cover - jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.models.layers import attention_scores
-
-
-def _axis_size(axis_name) -> int:
-    """Mesh-axis size inside a shard_map body; ``jax.lax.axis_size`` only
-    exists on newer jax, ``psum(1, axis)`` is the portable spelling."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def _ring_halo(x: jnp.ndarray, steps: int, axis: str) -> jnp.ndarray:
@@ -47,7 +35,7 @@ def _ring_halo(x: jnp.ndarray, steps: int, axis: str) -> jnp.ndarray:
     ring ppermute; returns (B, (steps+1)·S_shard, KV, hd) where the last
     S_shard rows are the local shard and earlier rows are predecessors
     (zeros beyond the sequence start)."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     parts = [x]
     cur = x
@@ -88,8 +76,8 @@ def windowed_attention_halo(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return attention_scores(qs, k_ext, v_ext, m[None], softcap)
 
     spec = P(None, axis, None, None)
-    return _shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec)(q, k, v)
+    return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec)(q, k, v)
 
 
 def halo_vs_gather_bytes(S: int, kv_heads: int, head_dim: int, *,

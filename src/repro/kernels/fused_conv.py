@@ -2,17 +2,25 @@
 op (paper Table I: CONV_BN / CONV_BN_RELU / ADD_RELU flags) re-tiled for
 the TPU memory hierarchy.
 
-PIM→TPU mapping (DESIGN.md §3): the paper's LBUF-resident spatial tile
-becomes a VMEM-resident output tile; the paper's GBUF weight broadcast
-becomes the weight BlockSpec (same weights revisited by every spatial grid
-step — XLA keeps them VMEM-resident); halo rows that cross PIM banks are
-here rows of the padded input loaded from ANY/HBM memory with dynamic
-slices.
+PIM→TPU mapping: the paper's LBUF-resident spatial tile becomes a
+VMEM-resident output tile; the paper's GBUF weight broadcast becomes the
+weight BlockSpec (same weights revisited by every spatial grid step); halo
+rows that cross PIM banks are here the extra rows of each tile's input
+window.  Neighbouring windows overlap by those rows, so the input
+BlockSpec indexes elements, not blocks (``pl.Element``), and the Pallas
+pipeline DMAs each window from HBM into VMEM, double-buffered.
 
-Grid: (batch, H-tiles, W-tiles, Cout-blocks).  Inner loop: kh × kw static
-unroll of (tile_pixels × Cin) · (Cin × Cout_blk) MXU matmuls accumulated in
-f32, then the BN/residual/ReLU epilogue — one HBM round-trip per tile for
-the whole fused layer group member.
+The wrapper turns every conv into a stride-1 conv the kernel reads with
+unit-stride static slices: a stride-s conv becomes a stride-1 conv over
+the space-to-depth input (each phase ``x[a::s, b::s]`` a channel group),
+and channels are padded to a multiple of 128 lanes.
+
+Grid: (batch, H-tiles, Cout-blocks).  Each tile spans the full output
+width (padded to a multiple of 8 so the tile reshapes cleanly onto the
+(8, 128) vreg layout).  Inner loop: kh × kw static unroll of
+(tile_pixels × Cin) · (Cin × Cout_blk) MXU matmuls accumulated in f32,
+then the BN/residual/ReLU epilogue — one HBM round-trip per tile for the
+whole fused layer group member.
 """
 
 from __future__ import annotations
@@ -24,54 +32,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams → CompilerParams across jax releases
-def _compiler_params(**kwargs):
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; this jax version is incompatible with the "
-            "fused_conv kernel")
-    return cls(**kwargs)
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def _kernel(x_ref, w_ref, scale_ref, shift_ref, *rest, stride: int,
-            kh: int, kw: int, th: int, tw: int, relu: bool,
-            has_residual: bool):
+def _kernel(x_ref, w_ref, scale_ref, shift_ref, *rest, kh: int, kw: int,
+            th: int, ow: int, relu: bool, has_residual: bool):
     if has_residual:
         res_ref, o_ref = rest
     else:
         (o_ref,) = rest
-    b = pl.program_id(0)
-    hi = pl.program_id(1)
-    wi = pl.program_id(2)
-
-    ih = hi * th * stride
-    iw = wi * tw * stride
-    in_h = (th - 1) * stride + kh
-    in_w = (tw - 1) * stride + kw
     cin = x_ref.shape[-1]
-    x_tile = pl.load(x_ref, (b, pl.dslice(ih, in_h), pl.dslice(iw, in_w),
-                             slice(None))).astype(jnp.float32)
-
     cout_blk = w_ref.shape[-1]
-    acc = jnp.zeros((th * tw, cout_blk), jnp.float32)
+    acc = jnp.zeros((th * ow, cout_blk), jnp.float32)
     for r in range(kh):
         for c in range(kw):
-            patch = jax.lax.slice(
-                x_tile, (r, c, 0),
-                (r + (th - 1) * stride + 1, c + (tw - 1) * stride + 1, cin),
-                (stride, stride, 1))                        # (th, tw, cin)
-            acc += jax.lax.dot_general(
-                patch.reshape(th * tw, cin),
-                w_ref[r, c].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            patch = x_ref[0, r:r + th, c:c + ow, :]         # (th, ow, cin)
+            acc += jnp.dot(patch.reshape(th * ow, cin).astype(jnp.float32),
+                           w_ref[r, c].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
 
     y = acc * scale_ref[...].astype(jnp.float32) \
         + shift_ref[...].astype(jnp.float32)
-    y = y.reshape(th, tw, cout_blk)
+    y = y.reshape(th, ow, cout_blk)
     if has_residual:
         y = y + res_ref[0].astype(jnp.float32)
     if relu:
@@ -79,64 +64,101 @@ def _kernel(x_ref, w_ref, scale_ref, shift_ref, *rest, stride: int,
     o_ref[0] = y.astype(o_ref.dtype)
 
 
+def _space_to_depth(xp: jnp.ndarray, w: jnp.ndarray, s: int):
+    """Stride-s conv over xp → stride-1 conv over the returned input.
+
+    Phase (a, b) of xp (rows a::s, cols b::s) becomes a channel group and
+    tap (r, c) moves to tap (r//s, c//s) of group (r%s, c%s).  Only phases
+    some tap reads are kept (a 1×1/s conv keeps one).  xp's H and W must
+    be multiples of s."""
+    kh, kw, cin, cout = w.shape
+    B, H, W, _ = xp.shape
+    skh, skw = -(-kh // s), -(-kw // s)
+    phases = sorted({(r % s, c % s) for r in range(kh) for c in range(kw)})
+    # reshapes and unit-stride slices only: XLA lowers strided slices of
+    # the padded map to gathers
+    xr = xp.reshape(B, H // s, s, W // s, s, cin)
+    wr = jnp.pad(w, ((0, skh * s - kh), (0, skw * s - kw), (0, 0), (0, 0))
+                 ).reshape(skh, s, skw, s, cin, cout)
+    xs = jnp.concatenate([xr[:, :, a, :, b] for a, b in phases], axis=-1)
+    ws = jnp.concatenate([wr[:, a, :, b] for a, b in phases], axis=-2)
+    return xs, ws
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "stride", "padding", "relu", "tile_h", "cout_block", "interpret"))
 def fused_conv_kernel(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray,
                       shift: jnp.ndarray, *, stride: int = 1,
                       padding: int = 1, relu: bool = True,
                       residual: jnp.ndarray | None = None,
-                      tile_h: int = 8, tile_w: int = 8,
-                      cout_block: int = 128,
-                      interpret: bool = True) -> jnp.ndarray:
+                      tile_h: int = 8, cout_block: int = 128,
+                      interpret: bool = False) -> jnp.ndarray:
     """x: (B, H, W, Cin) NHWC; w: (kh, kw, Cin, Cout).
     Returns (B, OH, OW, Cout) with OH = (H + 2p - kh)//s + 1."""
-    B, H, W, Cin = x.shape
+    B, H, W, _ = x.shape
     kh, kw, _, Cout = w.shape
-    OH = (H + 2 * padding - kh) // stride + 1
-    OW = (W + 2 * padding - kw) // stride + 1
-
-    th = min(tile_h, OH)
-    tw = min(tile_w, OW)
-    # pad output extent up to tile multiples; pad input accordingly
-    oh_pad = (-OH) % th
-    ow_pad = (-OW) % tw
+    s = stride
+    OH = (H + 2 * padding - kh) // s + 1
+    OW = (W + 2 * padding - kw) // s + 1
     cb = min(cout_block, Cout)
     assert Cout % cb == 0, f"cout {Cout} % block {cb}"
 
-    in_h_need = ((OH + oh_pad) - 1) * stride + kh
-    in_w_need = ((OW + ow_pad) - 1) * stride + kw
-    # with stride > kh the needed extent can be smaller than H: clamp pads
+    # output tiles: th rows × the full width padded to 8 columns
+    th = min(tile_h, OH)
+    oh = _round_up(OH, th)
+    ow = _round_up(OW, 8)
+    # stride-1 kernel extent after space-to-depth, and the input extent
+    # (in space-to-depth rows/cols) the padded output needs
+    skh, skw = -(-kh // s), -(-kw // s)
+    hs = oh + skh - 1
+    ws_ = _round_up(ow + skw - 1, 8)
+    # conv padding, then pad or crop to exactly hs·s × ws_·s input pixels
     xp = jnp.pad(x, ((0, 0),
-                     (padding, max(0, in_h_need - H - padding)),
-                     (padding, max(0, in_w_need - W - padding)), (0, 0)))
-    res = residual
-    if res is not None and (oh_pad or ow_pad):
-        res = jnp.pad(res, ((0, 0), (0, oh_pad), (0, ow_pad), (0, 0)))
+                     (padding, max(0, hs * s - H - padding)),
+                     (padding, max(0, ws_ * s - W - padding)),
+                     (0, 0)))[:, :hs * s, :ws_ * s]
+    xs, wsd = _space_to_depth(xp, w, s)
+    cp = _round_up(xs.shape[-1], 128)
+    xs = jnp.pad(xs, ((0, 0), (0, 0), (0, 0), (0, cp - xs.shape[-1])))
+    wsd = jnp.pad(wsd, ((0, 0), (0, 0), (0, cp - wsd.shape[2]), (0, 0)))
 
-    grid = (B, (OH + oh_pad) // th, (OW + ow_pad) // tw, Cout // cb)
-    kern = functools.partial(_kernel, stride=stride, kh=kh, kw=kw, th=th,
-                             tw=tw, relu=relu,
-                             has_residual=res is not None)
+    res = residual
+    if res is not None and (oh != OH or ow != OW):
+        res = jnp.pad(res, ((0, 0), (0, oh - OH), (0, ow - OW), (0, 0)))
+
+    grid = (B, oh // th, Cout // cb)
+    kern = functools.partial(_kernel, kh=skh, kw=skw, th=th, ow=ow,
+                             relu=relu, has_residual=res is not None)
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),               # x: HBM + dslice
-        pl.BlockSpec((kh, kw, Cin, cb), lambda b, h, w_, co: (0, 0, 0, co)),
-        pl.BlockSpec((cb,), lambda b, h, w_, co: (co,)),
-        pl.BlockSpec((cb,), lambda b, h, w_, co: (co,)),
+        # the tile's halo'd input window: rows [h·th, h·th + th + skh - 1)
+        pl.BlockSpec((pl.Element(1), pl.Element(th + skh - 1),
+                      pl.Element(ws_), pl.Element(cp)),
+                     lambda b, h, co: (b, h * th, 0, 0)),
+        pl.BlockSpec((skh, skw, cp, cb), lambda b, h, co: (0, 0, 0, co)),
+        pl.BlockSpec((1, cb), lambda b, h, co: (0, co)),
+        pl.BlockSpec((1, cb), lambda b, h, co: (0, co)),
     ]
-    args = [xp, w, scale, shift]
+    args = [xs, wsd, scale.reshape(1, Cout), shift.reshape(1, Cout)]
     if res is not None:
-        in_specs.append(pl.BlockSpec((1, th, tw, cb),
-                                     lambda b, h, w_, co: (b, h, w_, co)))
+        in_specs.append(pl.BlockSpec((1, th, ow, cb),
+                                     lambda b, h, co: (b, h, 0, co)))
         args.append(res)
 
+    # Left free, XLA places the call's operands and its output in VMEM from
+    # stage 2 on (a 33.5 MB output at 28x128->256, batch 128), beside the
+    # kernel's own VMEM blocks.  Pin them to HBM, where the BlockSpec
+    # pipeline DMAs from and to.  The interpreter knows no memory spaces.
+    space = pl.ANY if interpret else pltpu.HBM
+    args = [pltpu.with_memory_space_constraint(a, space) for a in args]
     out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th, tw, cb),
-                               lambda b, h, w_, co: (b, h, w_, co)),
-        out_shape=jax.ShapeDtypeStruct((B, OH + oh_pad, OW + ow_pad, Cout),
-                                       x.dtype),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel",) * 4),
+        out_specs=pl.BlockSpec((1, th, ow, cb),
+                               lambda b, h, co: (b, h, 0, co)),
+        out_shape=space((B, oh, ow, Cout), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3),
         interpret=interpret,
     )(*args)
     return out[:, :OH, :OW]

@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to auto-detection: interpret-mode on CPU (this
-container — validates kernel bodies in Python), compiled on real TPU.
+``fused_conv`` takes ``interpret`` from the caller and defaults to the
+compiled TPU kernel; CPU callers (tests, examples) pass ``interpret=True``.
+The sequence-model kernels pick interpret mode whenever the backend
+is not a TPU.
 """
 
 from __future__ import annotations
@@ -38,15 +40,14 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "relu",
-                                             "tile_h", "tile_w",
-                                             "cout_block"))
+                                             "tile_h", "cout_block",
+                                             "interpret"))
 def fused_conv(x, w, scale, shift, *, stride=1, padding=1, relu=True,
-               residual=None, tile_h=8, tile_w=8, cout_block=128):
+               residual=None, tile_h=8, cout_block=128, interpret=False):
     return fused_conv_kernel(x, w, scale, shift, stride=stride,
                              padding=padding, relu=relu, residual=residual,
-                             tile_h=tile_h, tile_w=tile_w,
-                             cout_block=cout_block,
-                             interpret=_auto_interpret())
+                             tile_h=tile_h, cout_block=cout_block,
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -45,13 +45,6 @@ from repro.train.trainer import (TrainStepConfig, make_serve_step,  # noqa: E402
                                  make_train_step, named, state_spec)
 
 
-def _mesh_context(mesh):
-    """``jax.set_mesh`` on newer jax; the Mesh's own (legacy global-mesh)
-    context manager on jax 0.4.x — both scope jit/lower to the mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 def _shape_only(tree):
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
@@ -122,7 +115,7 @@ def lower_cell(cell: Cell, mesh, policy_name: str, *, remat: bool = True,
             if policy_name == "layerwise_tp" \
             else hint_mod.fused_seq_hints(policy._dp())
         hint_ctx = hint_mod.sharding_hints(table)
-    with _mesh_context(mesh), hint_ctx:
+    with jax.set_mesh(mesh), hint_ctx:
         t0 = time.monotonic()
         lowered = fn.lower(*args)
         t1 = time.monotonic()

@@ -11,22 +11,30 @@ Mesh axes:
   choice plays out on (layer-by-layer ↔ TP gathers; fused ↔ sequence
   sharding with local halos)
 * ``pod``   — the multi-pod outer data axis (2 pods × 256 chips)
+
+Every mesh the repo builds comes from ``make_mesh`` and has Auto axis
+types: shardings propagate from the arguments' ``NamedSharding`` and
+``shard_map`` bodies run without a mesh context (``jax.make_mesh``
+defaults to Explicit axes, which would demand one).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
+              devices=None) -> jax.sharding.Mesh:
+    """Auto-typed mesh over ``devices`` (default: all of them)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    """Arbitrary mesh for tests/elastic re-meshing."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

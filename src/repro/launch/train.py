@@ -21,19 +21,14 @@ import jax
 from repro.configs import get_config
 from repro.core.policies import get_policy
 from repro.data.pipeline import batch_for_step
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.models.api import param_count
 from repro.optim.adamw import AdamWConfig
 from repro.train.fault_tolerance import StragglerWatch, run_restartable
 from repro.train.trainer import (TrainStepConfig, init_train_state,
                                  make_train_step, state_spec)
-
-
-def _mesh_context(mesh):
-    """``jax.set_mesh`` on newer jax; the Mesh's own (legacy global-mesh)
-    context manager on jax 0.4.x — both scope jit/lower to the mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
 
 
 def main() -> None:
@@ -55,9 +50,10 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
+    use_compile_cache()
 
     d, m = (int(v) for v in args.mesh.split("x"))
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     policy = get_policy(args.policy, mesh, cfg)
@@ -90,7 +86,7 @@ def main() -> None:
     count = [0]
 
     def step_and_log(state, batch):
-        with _mesh_context(mesh):
+        with jax.set_mesh(mesh):
             state, metrics = step_fn(state, batch)
         count[0] += 1
         k = count[0]

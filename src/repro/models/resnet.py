@@ -10,12 +10,13 @@ Two execution paths:
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.graph import OpKind, build_resnet18
 from repro.models import layers as L
 
 Params = dict[str, Any]
@@ -106,6 +107,40 @@ def forward_fused_groups(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     for g in groups:
         x = g(x)
     return tail(x)
+
+
+class ConvGeometry(NamedTuple):
+    """One ResNet18 conv as the fused CONV_BN[+ADD][+RELU] op sees it."""
+    hw: int            # input height = width
+    cin: int
+    cout: int
+    k: int
+    stride: int
+    padding: int
+    relu: bool
+    residual: bool     # ADD of the block's shortcut before the ReLU
+
+    @property
+    def name(self) -> str:
+        flags = "+ADD_RELU" if self.residual else "+RELU" if self.relu else ""
+        return (f"{self.k}x{self.k}/{self.stride} "
+                f"{self.hw}x{self.cin}->{self.cout}{flags}")
+
+
+def conv_geometries(image: int = 224) -> list[ConvGeometry]:
+    """The distinct conv geometries of ResNet18 on image×image inputs, in
+    network order, read off ``core.graph.build_resnet18``: each conv layer,
+    with a block's conv2 fused with the ADD_RELU that consumes it."""
+    layers = build_resnet18(image).layers
+    fused_add = {lyr.input_of for lyr in layers
+                 if lyr.kind is OpKind.ADD_RELU}
+    geoms = [ConvGeometry(lyr.iy, lyr.cin, lyr.cout, lyr.kh, lyr.stride,
+                          lyr.padding,
+                          relu=(lyr.kind is OpKind.CONV_BN_RELU
+                                or lyr.name in fused_add),
+                          residual=lyr.name in fused_add)
+             for lyr in layers if lyr.kind.is_conv]
+    return list(dict.fromkeys(geoms))
 
 
 def build_resnet_model(cfg: ModelConfig):

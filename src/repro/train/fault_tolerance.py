@@ -29,6 +29,7 @@ import jax
 
 from repro.checkpoint.ckpt import (CheckpointManager, latest_step,
                                    restore_checkpoint)
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +150,7 @@ def elastic_remesh(n_devices: int, *, model_parallel: int
         while model_parallel > 1 and n_devices % model_parallel:
             model_parallel //= 2
     data = n_devices // model_parallel
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+    return make_mesh((data, model_parallel), ("data", "model"))
 
 
 def reshard_state(state: Any, spec_tree: Any,

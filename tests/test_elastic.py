@@ -29,6 +29,7 @@ from repro.models import build_model
 from repro.core.policies import get_policy
 from repro.checkpoint.ckpt import save_checkpoint, restore_checkpoint
 from repro.train.trainer import named, state_spec
+from repro.launch.mesh import make_mesh
 from repro.train.fault_tolerance import elastic_remesh
 
 cfg = get_config('qwen3-32b', smoke=True)
@@ -36,7 +37,7 @@ model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 
 # shard on a 2x4 mesh, checkpoint
-mesh_a = jax.make_mesh((2, 4), ('data', 'model'))
+mesh_a = make_mesh((2, 4), ('data', 'model'))
 pol_a = get_policy('layerwise_tp', mesh_a, cfg)
 spec_a = pol_a.param_spec(params)
 sharded_a = pol_a.shard(params, spec_a)
@@ -63,8 +64,7 @@ from repro.train.trainer import TrainStepConfig, init_train_state, make_train_st
 from repro.data.pipeline import batch_for_step
 ts = TrainStepConfig(schedule_warmup=1)
 state = init_train_state(model, restored, ts)
-set_mesh = getattr(jax, 'set_mesh', None) or (lambda m: m)
-with set_mesh(mesh_b):
+with jax.set_mesh(mesh_b):
     state, metrics = jax.jit(make_train_step(model, ts))(
         state, batch_for_step(cfg, 0, 4, 16))
 assert np.isfinite(float(metrics['loss']))

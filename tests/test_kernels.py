@@ -112,7 +112,8 @@ def test_fused_conv_geometry(k, s, p, relu):
     scale = jax.random.normal(ks[2], (16,)) * 0.1 + 1.0
     shift = jax.random.normal(ks[3], (16,)) * 0.1
     out = fused_conv_kernel(x, w, scale, shift, stride=s, padding=p,
-                            relu=relu, tile_h=4, tile_w=4, cout_block=8)
+                            relu=relu, tile_h=4, cout_block=8,
+                            interpret=True)
     ref = fused_conv_ref(x, w, scale, shift, stride=s, padding=p, relu=relu)
     assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
@@ -127,7 +128,7 @@ def test_fused_conv_residual_add_relu():
     shift = jnp.zeros((8,))
     res = jax.random.normal(ks[2], (1, 8, 8, 8))
     out = fused_conv_kernel(x, w, scale, shift, residual=res, tile_h=4,
-                            tile_w=4, cout_block=8)
+                            cout_block=8, interpret=True)
     ref = fused_conv_ref(x, w, scale, shift, residual=res)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
     assert (np.asarray(out) >= 0).all()  # relu applied after add
@@ -139,7 +140,7 @@ def test_fused_conv_nondivisible_spatial():
     x = jax.random.normal(ks[0], (1, 7, 7, 8))
     w = jax.random.normal(ks[1], (3, 3, 8, 8)) * 0.2
     out = fused_conv_kernel(x, w, jnp.ones((8,)), jnp.zeros((8,)),
-                            tile_h=4, tile_w=4, cout_block=8)
+                            tile_h=4, cout_block=8, interpret=True)
     ref = fused_conv_ref(x, w, jnp.ones((8,)), jnp.zeros((8,)))
     assert out.shape == ref.shape == (1, 7, 7, 8)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
@@ -154,8 +155,8 @@ def test_fused_conv_property(hw, stride, k):
     x = jax.random.normal(ks[0], (1, hw, hw, 4))
     w = jax.random.normal(ks[1], (k, k, 4, 8)) * 0.3
     out = fused_conv_kernel(x, w, jnp.ones((8,)), jnp.zeros((8,)),
-                            stride=stride, padding=p, tile_h=2, tile_w=2,
-                            cout_block=8)
+                            stride=stride, padding=p, tile_h=2,
+                            cout_block=8, interpret=True)
     ref = fused_conv_ref(x, w, jnp.ones((8,)), jnp.zeros((8,)),
                          stride=stride, padding=p)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)

@@ -27,7 +27,6 @@ def test_policies_lower_both_meshes():
     policy must produce FEWER all-gather bytes (the paper's claim)."""
     out = _run("""
 import jax, json
-set_mesh = getattr(jax, 'set_mesh', None) or (lambda m: m)
 from repro.configs import get_config
 from repro.models import build_model
 from repro.core.policies import get_policy
@@ -35,8 +34,9 @@ from repro.train.trainer import TrainStepConfig, make_train_step, named, state_s
 from repro.data.pipeline import make_batch_specs
 from repro.optim.adamw import adamw_init
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 cfg = get_config('qwen3-32b', smoke=True)
 m = build_model(cfg)
 pshapes = jax.eval_shape(m.init, jax.random.PRNGKey(0))
@@ -46,7 +46,7 @@ for pol_name in ['layerwise_tp', 'fused_seq']:
     step = make_train_step(m, TrainStepConfig())
     batch = make_batch_specs(cfg, 8, 32)
     state_shapes = {'params': pshapes, 'opt': jax.eval_shape(adamw_init, pshapes)}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comp = jax.jit(step, in_shardings=(
             named(mesh, state_spec(pol, pshapes)),
             named(mesh, pol.batch_spec(batch)))).lower(
@@ -67,7 +67,8 @@ def test_repair_spec():
     import jax
 
     from repro.core.policies import repair_spec
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
     # trivial mesh: everything divisible by 1 → unchanged
     assert repair_spec(P("model", None), (7, 3), mesh) == P("model", None)
 
@@ -77,7 +78,8 @@ def test_repair_spec_drops_indivisible():
 import jax
 from jax.sharding import PartitionSpec as P
 from repro.core.policies import repair_spec
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 # dim0=1 cannot take data(2); dim1=122753 cannot take model(4)
 s = repair_spec(P('data', 'model'), (1, 122753), mesh)
 assert s == P(None, None), s
@@ -96,8 +98,9 @@ def test_halo_exchange_matches_monolithic():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.halo import run_fused_group_exact
+from repro.launch.mesh import make_mesh
 from repro.models.layers import conv2d, init_conv
-mesh = jax.make_mesh((8,), ('model',))
+mesh = make_mesh((8,), ('model',))
 key = jax.random.PRNGKey(0)
 ws = [init_conv(jax.random.fold_in(key, i), 3, 3, 16, 16, jnp.float32)
       for i in range(4)]
@@ -125,8 +128,9 @@ def test_halo_interior_exact_with_bias_layers():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.halo import run_fused_group
+from repro.launch.mesh import make_mesh
 from repro.models.resnet import init_resnet18, stage
-mesh = jax.make_mesh((8,), ('model',))
+mesh = make_mesh((8,), ('model',))
 key = jax.random.PRNGKey(0)
 p = init_resnet18(key, 10)
 x = jax.random.normal(key, (2, 64, 64, 64))
@@ -145,18 +149,16 @@ print('interior ok')
 def test_exchange_halo_boundaries():
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.halo import exchange_halo
-mesh = jax.make_mesh((4,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ('model',))
 x = jnp.arange(4 * 8, dtype=jnp.float32).reshape(1, 32, 1, 1)
 
 def f(xs):
     return exchange_halo(xs, 2, 2, 'model')
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
 y = shard_map(f, mesh=mesh, in_specs=(P(None, 'model', None, None),),
               out_specs=P(None, 'model', None, None))(x)
 y = np.asarray(y).reshape(4, 12)

@@ -25,7 +25,8 @@ def test_windowed_halo_matches_reference():
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.seq_halo import windowed_attention_halo
 from repro.kernels.ref import attention_ref
-mesh = jax.make_mesh((8,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('model',))
 key = jax.random.PRNGKey(0)
 B, S, H, KV, D = 2, 128, 4, 2, 16
 q = jax.random.normal(key, (B, S, H, D))
