@@ -1,1 +1,2 @@
-"""Benchmark harness: one module per paper table/figure + roofline."""
+"""Benchmark harness: one module per paper table/figure, and the chip
+benchmark under ``chip/``."""

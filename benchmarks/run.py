@@ -1,8 +1,9 @@
 """Benchmark runner: ``python -m benchmarks.run`` prints one CSV row per
 measurement: ``name,us_per_call,derived``.
 
-Covers every paper table/figure (PPA reproduction) + the roofline table
-from the committed dry-run artifacts (if present).
+Covers every paper table/figure (PPA reproduction), timed on the host
+CPU.  Device measurements on a TPU are the chip benchmark's
+(``benchmarks/chip/``, ``BENCHMARK.json``).
 """
 
 from __future__ import annotations
@@ -11,7 +12,7 @@ import sys
 
 
 def main() -> None:
-    from benchmarks import ppa_figures, roofline
+    from benchmarks import ppa_figures
 
     print("name,us_per_call,derived")
     failures = 0
@@ -23,12 +24,6 @@ def main() -> None:
             failures += 1
             print(f"{fn.__name__},0,ERROR:{type(e).__name__}:{e}",
                   file=sys.stderr)
-    try:
-        for row in roofline.run_benchmark():
-            print(row)
-    except Exception as e:  # noqa: BLE001
-        failures += 1
-        print(f"roofline,0,ERROR:{type(e).__name__}:{e}", file=sys.stderr)
     if failures:
         raise SystemExit(1)
 

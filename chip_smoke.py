@@ -16,7 +16,7 @@ maps against the same group on one device, and nothing else.
 Exits non-zero without a JSON line when JAX finds no TPU or any check
 fails.  On success the last stdout line is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
-Times printed on earlier lines are informational, not a benchmark.
+It times nothing: the chip benchmark (``benchmarks/chip/``) does.
 """
 
 from __future__ import annotations
@@ -25,7 +25,6 @@ import argparse
 import json
 import os
 import sys
-import time
 import traceback
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -55,7 +54,6 @@ TOL_HALO = 1e-4             # sharded vs one device, both "highest"
 
 IMAGE = 224
 BATCH = 128
-CALLS = 3
 
 
 class Checks:
@@ -83,24 +81,9 @@ def rel_err(out, ref) -> float:
     return float(np.abs(out - ref).max() / np.abs(ref).max())
 
 
-def compile_and_time(fn, *args):
-    """AOT-compile ``fn`` for ``args``, call it CALLS times; returns the
-    last output, compile seconds and per-call wall seconds."""
-    t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(*args).compile()
-    compile_s = time.perf_counter() - t0
-    call_s = []
-    for _ in range(CALLS):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(compiled(*args))
-        call_s.append(time.perf_counter() - t0)
-    return out, compile_s, call_s
-
-
-def info(name: str, compile_s: float, call_s: list[float]) -> None:
-    calls = ", ".join(f"{t:.6f}" for t in call_s)
-    print(f"  (info) {name}: compile {compile_s:.3f} s; "
-          f"calls [{calls}] s", flush=True)
+def compile_and_run(fn, *args):
+    """AOT-compile ``fn`` for ``args`` and return its output on them."""
+    return jax.block_until_ready(jax.jit(fn).lower(*args).compile()(*args))
 
 
 def resnet_phase(ck: Checks, key, batch: int) -> None:
@@ -109,13 +92,10 @@ def resnet_phase(ck: Checks, key, batch: int) -> None:
     params = model.init(key)
     x = jax.random.normal(jax.random.fold_in(key, 1),
                           (batch, IMAGE, IMAGE, 3), jnp.dtype(cfg.dtype))
-    mono, c_s, t_s = compile_and_time(forward, params, x)
-    info("resnet18 forward", c_s, t_s)
-    fused, c_s, t_s = compile_and_time(forward_fused_groups, params, x)
-    info("resnet18 forward_fused_groups", c_s, t_s)
+    mono = compile_and_run(forward, params, x)
+    fused = compile_and_run(forward_fused_groups, params, x)
     with jax.default_matmul_precision("highest"):
-        ref, c_s, t_s = compile_and_time(forward, params, x)
-    info("resnet18 forward @highest", c_s, t_s)
+        ref = compile_and_run(forward, params, x)
 
     shape = (batch, cfg.vocab_size)
     for name, y in (("forward", mono), ("forward_fused_groups", fused)):
@@ -152,8 +132,7 @@ def fused_conv_geometry(ck: Checks, key, batch: int,
     def ref(x, w, scale, shift, residual=None):
         return fused_conv_ref(x, w, scale, shift, residual=residual, **conv)
 
-    out, c_s, t_s = compile_and_time(kern, *args)
-    info(f"fused_conv {g.name}", c_s, t_s)
+    out = compile_and_run(kern, *args)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(ref)(*args)
     e = rel_err(out, want)
@@ -198,9 +177,8 @@ def halo_phase(ck: Checks, key, batch: int, devices) -> None:
 
     with jax.default_matmul_precision("highest"):
         want = jax.jit(group)(x1)
-        got, c_s, t_s = compile_and_time(
+        got = compile_and_run(
             lambda t: run_fused_group_exact(layers, t, mesh, halo=4), xs)
-        info("run_fused_group_exact x4", c_s, t_s)
         out_devs = {sh.device for sh in got.addressable_shards}
         e = rel_err(got, want)
         ck.check("run_fused_group_exact == one device (all rows)",
@@ -208,9 +186,8 @@ def halo_phase(ck: Checks, key, batch: int, devices) -> None:
                  f"{len(out_devs)} devices, rel err {e:.3e} <= {TOL_HALO:g}")
 
         want = jax.jit(block)(x1)
-        got, c_s, t_s = compile_and_time(
+        got = compile_and_run(
             lambda t: run_fused_group(block, t, mesh, halo=4, shrink=4), xs)
-        info("run_fused_group stage1 x4", c_s, t_s)
         shard = 56 // n
         inner = slice(shard, 56 - shard)       # rows of the interior shards
         e = rel_err(np.asarray(got)[:, inner], np.asarray(want)[:, inner])

@@ -6,6 +6,13 @@ Two execution paths:
   (stem+stage1 / stage2 / stage3 fused; stage4 + head layer-by-layer),
   structured so each fused group is a single fusable region (consumed by
   the Pallas fused-conv kernel and the halo-sharded distribution path).
+
+Both paths run the same layer functions, each under a ``jax.named_scope``
+named as the configurations' ``groups`` name their parts: ``stem``,
+``maxpool``, ``stage1`` … ``stage4``, ``head``.  The scopes are siblings,
+never nested in one another, so XLA carries exactly one layer name into
+each operation's ``op_name`` metadata; a profiler trace is attributed to
+layers by it.  Scopes are metadata only: they change no operation.
 """
 
 from __future__ import annotations
@@ -69,15 +76,28 @@ def init_resnet18(key, num_classes: int = 1000,
 
 
 def stem(p: Params, x: jnp.ndarray) -> jnp.ndarray:
-    h = jax.nn.relu(L.batchnorm(p["bn1"], L.conv2d(p["conv1"], x, 2, 3)))
-    return L.maxpool2d(h, 3, 2, 1)
+    """The 7x7/2 conv, BN and ReLU, then the 3x3/2 max-pool, each in a
+    named scope of its own (``stem``, ``maxpool``)."""
+    with jax.named_scope("stem"):
+        h = jax.nn.relu(L.batchnorm(p["bn1"], L.conv2d(p["conv1"], x, 2, 3)))
+    with jax.named_scope("maxpool"):
+        return L.maxpool2d(h, 3, 2, 1)
 
 
 def stage(p: Params, x: jnp.ndarray, si: int) -> jnp.ndarray:
-    for bi in range(2):
-        stride = 2 if (si > 0 and bi == 0) else 1
-        x = basic_block(p[f"s{si + 1}b{bi + 1}"], x, stride)
-    return x
+    """Stage ``si`` (0-based), in the named scope ``stage{si + 1}``."""
+    with jax.named_scope(f"stage{si + 1}"):
+        for bi in range(2):
+            stride = 2 if (si > 0 and bi == 0) else 1
+            x = basic_block(p[f"s{si + 1}b{bi + 1}"], x, stride)
+        return x
+
+
+def head(p: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """Global average pool and the fully connected layer, in the named
+    scope ``head``."""
+    with jax.named_scope("head"):
+        return L.avgpool_global(x) @ p["fc_w"] + p["fc_b"]
 
 
 def forward(p: Params, x: jnp.ndarray) -> jnp.ndarray:
@@ -85,8 +105,7 @@ def forward(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     h = stem(p, x)
     for si in range(4):
         h = stage(p, h, si)
-    h = L.avgpool_global(h)
-    return h @ p["fc_w"] + p["fc_b"]
+    return head(p, h)
 
 
 # --- fused-group structure (paper's Fused4 grouping) ---
@@ -99,7 +118,7 @@ def fused_group_fns(p: Params):
         lambda x: stage(p, stem(p, x), 0),
         lambda x: stage(p, x, 1),
         lambda x: stage(p, x, 2),
-    ], lambda x: (L.avgpool_global(stage(p, x, 3)) @ p["fc_w"] + p["fc_b"])
+    ], lambda x: head(p, stage(p, x, 3))
 
 
 def forward_fused_groups(p: Params, x: jnp.ndarray) -> jnp.ndarray:

@@ -1,11 +1,16 @@
-"""AOT compiles of the ``fused_conv`` Pallas kernel for a TPU v5e, at every
-conv geometry of ResNet18 on 224×224 images with a batch of 128.
+"""AOT compiles for a TPU v5e: the ``fused_conv`` Pallas kernel at every
+conv geometry of ResNet18 on 224×224 images with a batch of 128, and
+ResNet18 itself, whose operations must each carry one layer scope.
 
 Nothing runs: each test lowers the compiled kernel (``interpret=False``)
 for one chip of a v5e:2x2 topology described without hardware, so what
 Mosaic refuses (an unaligned slice, a strided access it cannot lower, too
 much VMEM) fails here instead of on the chip.  Each test also checks that
-XLA left the kernel's operands and output in HBM.  The topology is described
+XLA left the kernel's operands and output in HBM.  The ResNet18 compiles
+check that every operation that computes (a fusion, a conv, a pooling
+window, a custom-call) carries exactly one of the model's layer scopes in
+its ``op_name``: the chip benchmark attributes device time to layers and
+fused groups by them.  The topology is described
 inside a fixture, never at import: only one process at a time may load the
 TPU library, and every test worker imports this file.
 """
@@ -19,9 +24,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.fused_conv import fused_conv_kernel
-from repro.models.resnet import conv_geometries
+from repro.models.resnet import (conv_geometries, forward,
+                                 forward_fused_groups, init_resnet18)
 
 BATCH = 128
+LAYERS = {"stem", "maxpool", "stage1", "stage2", "stage3", "stage4", "head"}
+# operations that compute; the rest of ENTRY moves weights or the input
+SCOPED = {"fusion", "convolution", "reduce-window", "custom-call"}
 
 
 @pytest.fixture(scope="module")
@@ -86,3 +95,47 @@ def custom_call_types(hlo: str) -> list[str]:
         return []
     operands = [o.strip().lstrip("%") for o in call.group(2).split(",")]
     return [call.group(1)] + [defs[o] for o in operands]
+
+
+@pytest.mark.parametrize("entry, batch", [(forward, 1),
+                                          (forward_fused_groups, BATCH)],
+                         ids=["forward-b1", "forward_fused_groups-b128"])
+def test_resnet18_ops_carry_one_layer_scope(entry, batch, one_chip,
+                                            no_persistent_cache):
+    def spec(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(init_resnet18,
+                                               jax.random.key(0)))
+    x = spec(jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
+
+    def call(p, x):
+        with jax.default_matmul_precision("highest"):
+            return entry(p, x)
+
+    hlo = jax.jit(call).lower(params, x).compile().as_text()
+    seen = set()
+    for opcode, target, op_name in entry_instructions(hlo):
+        # XLA's reassembly of weight slices prefetched into VMEM
+        if opcode not in SCOPED or target == "ConcatBitcast":
+            continue
+        layers = [c for c in op_name.split("/") if c in LAYERS]
+        assert len(layers) == 1, (opcode, target, op_name)
+        seen.update(layers)
+    assert seen == LAYERS
+
+
+def entry_instructions(hlo: str) -> list[tuple[str, str, str]]:
+    """(opcode, custom-call target, op_name) of each instruction of the
+    ENTRY computation in optimized HLO text."""
+    body = hlo[hlo.index("\nENTRY "):]
+    body = body[body.index("\n") + 1:body.index("\n}\n")]
+    out = []
+    for line in body.splitlines():
+        opcode = re.search(r"(?:\)|\]|\}) ([a-z][\w\-]*)\(", line)
+        target = re.search(r'custom_call_target="([^"]*)"', line)
+        op_name = re.search(r'op_name="((?:[^"\\]|\\.)*)"', line)
+        out.append((opcode.group(1) if opcode else "",
+                    target.group(1) if target else "",
+                    op_name.group(1) if op_name else ""))
+    return out
