@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.models.layers import space_to_depth
+
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
@@ -62,27 +64,6 @@ def _kernel(x_ref, w_ref, scale_ref, shift_ref, *rest, kh: int, kw: int,
     if relu:
         y = jnp.maximum(y, 0.0)
     o_ref[0] = y.astype(o_ref.dtype)
-
-
-def _space_to_depth(xp: jnp.ndarray, w: jnp.ndarray, s: int):
-    """Stride-s conv over xp → stride-1 conv over the returned input.
-
-    Phase (a, b) of xp (rows a::s, cols b::s) becomes a channel group and
-    tap (r, c) moves to tap (r//s, c//s) of group (r%s, c%s).  Only phases
-    some tap reads are kept (a 1×1/s conv keeps one).  xp's H and W must
-    be multiples of s."""
-    kh, kw, cin, cout = w.shape
-    B, H, W, _ = xp.shape
-    skh, skw = -(-kh // s), -(-kw // s)
-    phases = sorted({(r % s, c % s) for r in range(kh) for c in range(kw)})
-    # reshapes and unit-stride slices only: XLA lowers strided slices of
-    # the padded map to gathers
-    xr = xp.reshape(B, H // s, s, W // s, s, cin)
-    wr = jnp.pad(w, ((0, skh * s - kh), (0, skw * s - kw), (0, 0), (0, 0))
-                 ).reshape(skh, s, skw, s, cin, cout)
-    xs = jnp.concatenate([xr[:, :, a, :, b] for a, b in phases], axis=-1)
-    ws = jnp.concatenate([wr[:, a, :, b] for a, b in phases], axis=-2)
-    return xs, ws
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -117,7 +98,7 @@ def fused_conv_kernel(x: jnp.ndarray, w: jnp.ndarray, scale: jnp.ndarray,
                      (padding, max(0, hs * s - H - padding)),
                      (padding, max(0, ws_ * s - W - padding)),
                      (0, 0)))[:, :hs * s, :ws_ * s]
-    xs, wsd = _space_to_depth(xp, w, s)
+    xs, wsd = space_to_depth(xp, w, s)
     cp = _round_up(xs.shape[-1], 128)
     xs = jnp.pad(xs, ((0, 0), (0, 0), (0, 0), (0, cp - xs.shape[-1])))
     wsd = jnp.pad(wsd, ((0, 0), (0, 0), (0, cp - wsd.shape[2]), (0, 0)))

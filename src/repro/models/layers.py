@@ -195,13 +195,64 @@ def init_conv(key, kh: int, kw: int, cin: int, cout: int, dtype) -> jnp.ndarray:
             * math.sqrt(2.0 / fan_in)).astype(dtype)
 
 
+# contraction depth of the TPU v5e matrix unit
+MXU_DEPTH = 128
+
+
+def space_to_depth(xp: jnp.ndarray, w: jnp.ndarray, s: int):
+    """Stride-s conv over xp → stride-1 conv over the returned input.
+
+    Phase (a, b) of xp (rows a::s, cols b::s) becomes a channel group and
+    tap (r, c) moves to tap (r//s, c//s) of group (r%s, c%s).  Only phases
+    some tap reads are kept (a 1×1/s conv keeps one).  xp's H and W must
+    be multiples of s."""
+    kh, kw, cin, cout = w.shape
+    B, H, W, _ = xp.shape
+    skh, skw = -(-kh // s), -(-kw // s)
+    pa, pb = min(kh, s), min(kw, s)          # the phases some tap reads
+    # reshapes, unit-stride slices and one transpose: XLA lowers strided
+    # slices of the map to gathers
+    xs = xp.reshape(B, H // s, s, W // s, s, cin)[:, :, :pa, :, :pb]
+    xs = xs.transpose(0, 1, 3, 2, 4, 5).reshape(B, H // s, W // s, -1)
+    ws = jnp.pad(w, ((0, skh * s - kh), (0, skw * s - kw), (0, 0), (0, 0))
+                 ).reshape(skh, s, skw, s, cin, cout)[:, :pa, :, :pb]
+    ws = ws.transpose(0, 2, 1, 3, 4, 5).reshape(skh, skw, -1, cout)
+    return xs, ws
+
+
 def conv2d(w: jnp.ndarray, x: jnp.ndarray, stride: int = 1,
            padding: int = 0) -> jnp.ndarray:
-    """x: NHWC, w: HWIO."""
+    """x: NHWC, w: HWIO.
+
+    A strided conv whose input has so few channels that stride² · Cin
+    still fits the matrix unit's contraction (ResNet's 7×7/2 stem on 3
+    channels) runs as a stride-1 conv over the space-to-depth input: a
+    4×4 conv over 12 channels in place of a 7×7/2 over 3.  The kernel
+    takes zero taps in front, so that the leading padding is whole
+    space-to-depth pixels the conv pads itself, and behind, up to a
+    multiple of the stride; the map is relaid out once, unpadded.  Same
+    products; the zero taps add exact zeros."""
+    kh, kw, cin, _ = w.shape
+    s, p = stride, padding
+    dims = ("NHWC", "HWIO", "NHWC")
+    if s == 1 or s * s * cin > MXU_DEPTH:
+        return jax.lax.conv_general_dilated(
+            x, w, window_strides=(s, s), padding=[(p, p), (p, p)],
+            dimension_numbers=dims)
+    _, H, W, _ = x.shape
+    oh, ow = (H + 2 * p - kh) // s + 1, (W + 2 * p - kw) // s + 1
+    lead = -(-p // s)                      # leading padding, in s2d pixels
+    t = lead * s - p
+    w = jnp.pad(w, ((t, 0), (t, 0), (0, 0), (0, 0)))
+    x = jnp.pad(x, ((0, 0), (0, -H % s), (0, -W % s), (0, 0)))
+    xs, ws = space_to_depth(x, w, s)
+    # the s2d rows and cols the output reads past the leading padding
+    hs, wd = oh + ws.shape[0] - 1 - lead, ow + ws.shape[1] - 1 - lead
+    xs = xs[:, :hs, :wd]
     return jax.lax.conv_general_dilated(
-        x, w, window_strides=(stride, stride),
-        padding=[(padding, padding), (padding, padding)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        xs, ws, window_strides=(1, 1),
+        padding=[(lead, hs - xs.shape[1]), (lead, wd - xs.shape[2])],
+        dimension_numbers=dims)
 
 
 def init_bn(cout: int, dtype) -> Params:

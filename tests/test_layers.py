@@ -126,6 +126,34 @@ def test_conv2d_identity_kernel():
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("hw, k, stride, pad, cin, cout, features, step", [
+    (224, 7, 2, 3, 3, 64, 12, 1),     # the stem: space-to-depth, 2·2·3
+    (64, 7, 2, 3, 3, 64, 12, 1),
+    (65, 7, 2, 3, 3, 64, 12, 1),      # odd: a row and a col padded
+    (56, 3, 2, 1, 64, 128, 64, 2),    # 2·2·64 > 128: the plain strided conv
+    (56, 1, 2, 0, 64, 128, 64, 2),
+], ids=["stem-224", "stem-64", "stem-65", "3x3s2-c64", "1x1s2-c64"])
+def test_conv2d_strided_matches_lax_and_takes_space_to_depth_by_shape(
+        hw, k, stride, pad, cin, cout, features, step):
+    x = jax.random.normal(KEY, (2, hw, hw, cin))
+    w = jax.random.normal(jax.random.fold_in(KEY, 1), (k, k, cin, cout))
+    ref = jax.lax.conv_general_dilated(
+        x, w, (stride, stride), [(pad, pad), (pad, pad)],
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
+    with jax.default_matmul_precision("highest"):
+        y = L.conv2d(w, x, stride, pad)
+        jaxpr = jax.make_jaxpr(L.conv2d, static_argnums=(2, 3))(
+            w, x, stride, pad)
+    assert y.shape == ref.shape
+    err = jnp.max(jnp.abs(y - ref)) / jnp.max(jnp.abs(ref))
+    assert float(err) <= 1e-6
+    convs = [e for e in jaxpr.eqns if e.primitive.name == "conv_general_dilated"]
+    assert len(convs) == 1
+    assert convs[0].invars[0].aval.shape[-1] == features
+    assert convs[0].params["window_strides"] == (step, step)
+
+
 def test_maxpool_basic():
     x = jnp.arange(16.0).reshape(1, 4, 4, 1)
     y = L.maxpool2d(x, 2, 2, 0)

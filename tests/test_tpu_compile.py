@@ -10,7 +10,8 @@ XLA left the kernel's operands and output in HBM.  The ResNet18 compiles
 check that every operation that computes (a fusion, a conv, a pooling
 window, a custom-call) carries exactly one of the model's layer scopes in
 its ``op_name``: the chip benchmark attributes device time to layers and
-fused groups by them.  The topology is described
+fused groups by them; and that the 7x7/2 stem became a 4x4 conv over a
+space-to-depth input of 12 channels.  The topology is described
 inside a fixture, never at import: only one process at a time may load the
 TPU library, and every test worker imports this file.
 """
@@ -97,25 +98,40 @@ def custom_call_types(hlo: str) -> list[str]:
     return [call.group(1)] + [defs[o] for o in operands]
 
 
-@pytest.mark.parametrize("entry, batch", [(forward, 1),
-                                          (forward_fused_groups, BATCH)],
-                         ids=["forward-b1", "forward_fused_groups-b128"])
-def test_resnet18_ops_carry_one_layer_scope(entry, batch, one_chip,
-                                            no_persistent_cache):
+PROGRAMS = pytest.mark.parametrize(
+    "entry, batch", [(forward, 1), (forward_fused_groups, BATCH)],
+    ids=["forward-b1", "forward_fused_groups-b128"])
+
+
+@pytest.fixture(scope="module")
+def resnet18_hlo(one_chip, no_persistent_cache):
+    """Optimized HLO text of ResNet18 at ``highest``, compiled once per
+    entry point and batch for the described chip."""
+    compiled = {}
+
     def spec(s):
         return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
 
-    params = jax.tree.map(spec, jax.eval_shape(init_resnet18,
-                                               jax.random.key(0)))
-    x = spec(jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
+    def hlo(entry, batch):
+        if (entry, batch) not in compiled:
+            params = jax.tree.map(spec, jax.eval_shape(init_resnet18,
+                                                       jax.random.key(0)))
+            x = spec(jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
 
-    def call(p, x):
-        with jax.default_matmul_precision("highest"):
-            return entry(p, x)
+            def call(p, x):
+                with jax.default_matmul_precision("highest"):
+                    return entry(p, x)
+            compiled[entry, batch] = \
+                jax.jit(call).lower(params, x).compile().as_text()
+        return compiled[entry, batch]
+    return hlo
 
-    hlo = jax.jit(call).lower(params, x).compile().as_text()
+
+@PROGRAMS
+def test_resnet18_ops_carry_one_layer_scope(entry, batch, resnet18_hlo):
     seen = set()
-    for opcode, target, op_name in entry_instructions(hlo):
+    for opcode, target, op_name in entry_instructions(
+            resnet18_hlo(entry, batch)):
         # XLA's reassembly of weight slices prefetched into VMEM
         if opcode not in SCOPED or target == "ConcatBitcast":
             continue
@@ -123,6 +139,25 @@ def test_resnet18_ops_carry_one_layer_scope(entry, batch, one_chip,
         assert len(layers) == 1, (opcode, target, op_name)
         seen.update(layers)
     assert seen == LAYERS
+
+
+@PROGRAMS
+def test_resnet18_stem_conv_runs_over_space_to_depth(entry, batch,
+                                                      resnet18_hlo):
+    """The 7x7/2 stem on 3 channels compiles to a 4x4 stride-1 conv over
+    12 channels, and no space-to-depth became a gather."""
+    hlo = resnet18_hlo(entry, batch)
+    shapes = {name: [int(d) for d in dims.split(",") if d] for name, dims
+              in re.findall(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]",
+                            hlo, re.M)}
+    convs = re.findall(r" convolution\(%(\S+), %\S+\), "
+                       r"window=\{size=(\w+)[^}]*\}, dim_labels=(\w+)_"
+                       r".*op_name=\"[^\"]*/stem/", hlo)
+    assert convs
+    for lhs, window, labels in convs:
+        assert window == "4x4"
+        assert shapes[lhs][labels.index("f")] == 12
+    assert " gather(" not in hlo
 
 
 def entry_instructions(hlo: str) -> list[tuple[str, str, str]]:
