@@ -172,6 +172,7 @@ ARCH_REGISTRY = [
 _MODULE_FOR = {name: "repro.configs." + name.replace("-", "_").replace(".", "_")
                for name in ARCH_REGISTRY}
 _MODULE_FOR["resnet18"] = "repro.configs.resnet18"
+_MODULE_FOR["convnext-tiny"] = "repro.configs.convnext_tiny"
 
 
 def get_config(name: str, smoke: bool = False) -> ModelConfig:

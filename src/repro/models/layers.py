@@ -1,5 +1,5 @@
 """Core NN layers in pure JAX: norms, RoPE, GQA attention, gated MLPs,
-embeddings, and the conv/bn/pool set for ResNet.
+embeddings, and the conv/bn/pool set for the CNNs.
 
 Conventions:
 * parameters are plain nested dicts of ``jnp.ndarray``;
@@ -185,8 +185,14 @@ def mlp(p: Params, x: jnp.ndarray, activation: str = "silu") -> jnp.ndarray:
     return (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
 
 
+def gelu(x: jnp.ndarray) -> jnp.ndarray:
+    """The exact GELU, x·Φ(x), through erf: ``jax.nn.gelu``'s erfc form
+    lowers on a TPU to sign-mask fusions that carry no layer scope."""
+    return 0.5 * x * (1.0 + jax.lax.erf(x * math.sqrt(0.5)))
+
+
 # ---------------------------------------------------------------------------
-# conv/bn/pool for ResNet
+# conv/bn/pool for the CNNs (ResNet, ConvNeXt)
 # ---------------------------------------------------------------------------
 
 def init_conv(key, kh: int, kw: int, cin: int, cout: int, dtype) -> jnp.ndarray:
@@ -221,8 +227,9 @@ def space_to_depth(xp: jnp.ndarray, w: jnp.ndarray, s: int):
 
 
 def conv2d(w: jnp.ndarray, x: jnp.ndarray, stride: int = 1,
-           padding: int = 0) -> jnp.ndarray:
-    """x: NHWC, w: HWIO.
+           padding: int = 0, groups: int = 1) -> jnp.ndarray:
+    """x: NHWC, w: HWIO, with I = Cin / groups (``groups = Cin``: a
+    depthwise conv, w of shape (kh, kw, 1, Cin)).
 
     A strided conv whose input has so few channels that stride² · Cin
     still fits the matrix unit's contraction (ResNet's 7×7/2 stem on 3
@@ -231,14 +238,15 @@ def conv2d(w: jnp.ndarray, x: jnp.ndarray, stride: int = 1,
     takes zero taps in front, so that the leading padding is whole
     space-to-depth pixels the conv pads itself, and behind, up to a
     multiple of the stride; the map is relaid out once, unpadded.  Same
-    products; the zero taps add exact zeros."""
+    products; the zero taps add exact zeros.  Grouped convs keep the
+    plain form."""
     kh, kw, cin, _ = w.shape
     s, p = stride, padding
     dims = ("NHWC", "HWIO", "NHWC")
-    if s == 1 or s * s * cin > MXU_DEPTH:
+    if s == 1 or s * s * cin > MXU_DEPTH or groups != 1:
         return jax.lax.conv_general_dilated(
             x, w, window_strides=(s, s), padding=[(p, p), (p, p)],
-            dimension_numbers=dims)
+            dimension_numbers=dims, feature_group_count=groups)
     _, H, W, _ = x.shape
     oh, ow = (H + 2 * p - kh) // s + 1, (W + 2 * p - kw) // s + 1
     lead = -(-p // s)                      # leading padding, in s2d pixels

@@ -22,7 +22,6 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
 from repro.core.graph import OpKind, build_resnet18
 from repro.models import layers as L
 
@@ -161,19 +160,3 @@ def conv_geometries(image: int = 224) -> list[ConvGeometry]:
              for lyr in layers if lyr.kind.is_conv]
     return list(dict.fromkeys(geoms))
 
-
-def build_resnet_model(cfg: ModelConfig):
-    from repro.models.api import Model
-    dtype = jnp.dtype(cfg.param_dtype)
-
-    def init(key):
-        return init_resnet18(key, cfg.vocab_size, dtype)
-
-    def fwd(params, batch, *, remat: bool = False,
-            return_hidden: bool = False):
-        return forward(params, batch["images"]), jnp.float32(0.0)
-
-    def no_cache(*a, **k):
-        raise NotImplementedError("CNN classifier has no decode path")
-
-    return Model(cfg, init, fwd, no_cache, no_cache)

@@ -132,7 +132,10 @@ def test_conv2d_identity_kernel():
     (65, 7, 2, 3, 3, 64, 12, 1),      # odd: a row and a col padded
     (56, 3, 2, 1, 64, 128, 64, 2),    # 2·2·64 > 128: the plain strided conv
     (56, 1, 2, 0, 64, 128, 64, 2),
-], ids=["stem-224", "stem-64", "stem-65", "3x3s2-c64", "1x1s2-c64"])
+    (224, 4, 4, 0, 3, 96, 48, 1),     # ConvNeXt's patchify stem: 4·4·3
+    (56, 2, 2, 0, 96, 192, 96, 2),    # its downsample: 2·2·96 > 128
+], ids=["stem-224", "stem-64", "stem-65", "3x3s2-c64", "1x1s2-c64",
+        "patchify-224", "down-2x2s2-c96"])
 def test_conv2d_strided_matches_lax_and_takes_space_to_depth_by_shape(
         hw, k, stride, pad, cin, cout, features, step):
     x = jax.random.normal(KEY, (2, hw, hw, cin))
@@ -152,6 +155,48 @@ def test_conv2d_strided_matches_lax_and_takes_space_to_depth_by_shape(
     assert len(convs) == 1
     assert convs[0].invars[0].aval.shape[-1] == features
     assert convs[0].params["window_strides"] == (step, step)
+
+
+@pytest.mark.parametrize("hw, k, c", [(14, 7, 384), (7, 7, 768), (9, 3, 5)])
+def test_conv2d_depthwise_matches_shifted_sum(hw, k, c):
+    """``groups = C``: each channel convolved with its own k×k kernel, a
+    sum of k·k shifted elementwise products."""
+    x = jax.random.normal(KEY, (2, hw, hw, c))
+    w = jax.random.normal(jax.random.fold_in(KEY, 1), (k, k, 1, c))
+    p = k // 2
+    xp = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
+    ref = sum(xp[:, i:i + hw, j:j + hw] * w[i, j, 0]
+              for i in range(k) for j in range(k))
+    with jax.default_matmul_precision("highest"):
+        y = L.conv2d(w, x, 1, p, groups=c)
+    assert y.shape == ref.shape
+    assert float(jnp.max(jnp.abs(y - ref)) / jnp.max(jnp.abs(ref))) <= 1e-6
+
+
+def test_resnet18_convs_lower_ungrouped_and_convnext_depthwise():
+    from repro.models import convnext, resnet
+    x = jnp.zeros((1, 32, 32, 3))
+
+    def groups(init, forward):
+        p = jax.eval_shape(init, jax.random.key(0))
+        eqns = jax.make_jaxpr(forward)(p, x).eqns
+        return [e.params["feature_group_count"] for e in eqns
+                if e.primitive.name == "conv_general_dilated"]
+
+    counts = groups(resnet.init_resnet18, resnet.forward)
+    assert len(counts) == 20 and set(counts) == {1}
+    counts = groups(convnext.init_convnext_tiny, convnext.forward)
+    depthwise = [c for c in counts if c != 1]
+    assert len(depthwise) == sum(convnext.DEPTHS)
+    assert set(depthwise) == set(convnext.DIMS)
+
+
+def test_gelu_is_exact():
+    x = jnp.linspace(-6.0, 6.0, 1001)
+    np.testing.assert_allclose(np.asarray(L.gelu(x)),
+                               np.asarray(jax.nn.gelu(x, approximate=False)),
+                               atol=1e-6)
+    assert float(jnp.max(jnp.abs(L.gelu(x) - jax.nn.gelu(x)))) > 1e-4
 
 
 def test_maxpool_basic():

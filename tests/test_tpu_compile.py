@@ -1,6 +1,7 @@
 """AOT compiles for a TPU v5e: the ``fused_conv`` Pallas kernel at every
 conv geometry of ResNet18 on 224×224 images with a batch of 128, and
-ResNet18 itself, whose operations must each carry one layer scope.
+ResNet18 and ConvNeXt-T themselves, whose operations must each carry one
+layer scope.
 
 Nothing runs: each test lowers the compiled kernel (``interpret=False``)
 for one chip of a v5e:2x2 topology described without hardware, so what
@@ -11,7 +12,9 @@ check that every operation that computes (a fusion, a conv, a pooling
 window, a custom-call) carries exactly one of the model's layer scopes in
 its ``op_name``: the chip benchmark attributes device time to layers and
 fused groups by them; and that the 7x7/2 stem became a 4x4 conv over a
-space-to-depth input of 12 channels.  The topology is described
+space-to-depth input of 12 channels.  The ConvNeXt-T compiles check the
+same of its stage scopes, and that its blocks' ``dwconv`` and ``mlp``
+scopes reach the compiled operations.  The topology is described
 inside a fixture, never at import: only one process at a time may load the
 TPU library, and every test worker imports this file.
 """
@@ -25,6 +28,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.fused_conv import fused_conv_kernel
+from repro.models import convnext
 from repro.models.resnet import (conv_geometries, forward,
                                  forward_fused_groups, init_resnet18)
 
@@ -158,6 +162,44 @@ def test_resnet18_stem_conv_runs_over_space_to_depth(entry, batch,
         assert window == "4x4"
         assert shapes[lhs][labels.index("f")] == 12
     assert " gather(" not in hlo
+
+
+CONVNEXT_LAYERS = {"stem", "stage1", "stage2", "stage3", "stage4", "head"}
+
+
+@pytest.mark.parametrize("batch", [BATCH, 1])
+def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
+        batch, one_chip, no_persistent_cache):
+    """ConvNeXt-T at 224x224, float32 at ``highest``: every operation that
+    computes carries exactly one stage-level scope, and the block's
+    ``dwconv`` and ``mlp`` scopes reach the compiled operations, with the
+    depthwise convs (``feature_group_count``) under ``dwconv``."""
+    def spec(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(convnext.init_convnext_tiny,
+                                               jax.random.key(0)))
+    x = spec(jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
+
+    def call(p, x):
+        with jax.default_matmul_precision("highest"):
+            return convnext.forward(p, x)
+    hlo = jax.jit(call).lower(params, x).compile().as_text()
+    seen, kinds = set(), set()
+    for opcode, target, op_name in entry_instructions(hlo):
+        if opcode not in SCOPED or target == "ConcatBitcast":
+            continue
+        path = op_name.split("/")
+        layers = [c for c in path if c in CONVNEXT_LAYERS]
+        assert len(layers) == 1, (opcode, target, op_name)
+        seen.update(layers)
+        kinds.update(c for c in path if c in ("dwconv", "mlp"))
+    assert seen == CONVNEXT_LAYERS and kinds == {"dwconv", "mlp"}
+    depthwise = re.findall(r" convolution\(.*feature_group_count=(\d+).*"
+                           r'op_name="([^"]*)"', hlo)
+    assert len(depthwise) >= sum(convnext.DEPTHS)
+    for groups, op_name in depthwise:
+        assert int(groups) in convnext.DIMS and "/dwconv/" in op_name
 
 
 def entry_instructions(hlo: str) -> list[tuple[str, str, str]]:
