@@ -16,9 +16,10 @@ per-channel layer scale γ.
 Scopes follow ``resnet.py``: ``stem``, ``stage1`` … ``stage4`` (each
 downsample in the scope of the stage it opens) and ``head`` are siblings,
 so each operation carries exactly one of them.  Inside each block two
-nested scopes split a trace by kind: ``dwconv`` (the depthwise conv and
-its bias) and ``mlp`` (LayerNorm, expansion, GELU, projection, layer
-scale).  The residual add is in neither.
+nested scopes split a trace by kind: ``dwconv`` (``layers.depthwise_conv``:
+the depthwise conv, its bias, and each pixel's sum over channels, which
+the LayerNorm takes for its mean) and ``mlp`` (LayerNorm, expansion,
+GELU, projection, layer scale).  The residual add is in neither.
 
 ``forward`` reads every size from the parameters, so the same function
 runs any depth and width of the family.
@@ -85,10 +86,9 @@ def init_convnext_tiny(key, num_classes: int = 1000,
 
 def block(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("dwconv"):
-        k = p["dw_w"].shape[0]
-        h = L.conv2d(p["dw_w"], x, 1, k // 2, groups=x.shape[-1]) + p["dw_b"]
+        h, total = L.depthwise_conv(p["dw_w"], x, p["dw_b"])
     with jax.named_scope("mlp"):
-        h = L.layernorm(p["ln"], h, LN_EPS)
+        h = L.layernorm(p["ln"], h, LN_EPS, total)
         h = L.gelu(h @ p["w1"] + p["b1"])
         h = (h @ p["w2"] + p["b2"]) * p["gamma"]
     return x + h

@@ -11,6 +11,7 @@ Conventions:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -53,11 +54,17 @@ def init_layernorm(dim: int, dtype) -> Params:
     return {"scale": jnp.ones((dim,), dtype), "bias": jnp.zeros((dim,), dtype)}
 
 
-def layernorm(p: Params, x: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
+def layernorm(p: Params, x: jnp.ndarray, eps: float = 1e-5,
+              total: jnp.ndarray | None = None) -> jnp.ndarray:
+    """LayerNorm over the last axis; ``total``, x's float32 sum over that
+    axis where its producer has it (``depthwise_conv``), gives the mean."""
     dt = x.dtype
     x32 = x.astype(jnp.float32)
-    mu = jnp.mean(x32, axis=-1, keepdims=True)
-    var = jnp.var(x32, axis=-1, keepdims=True)
+    if total is None:
+        mu = jnp.mean(x32, axis=-1, keepdims=True)
+    else:
+        mu = total[..., None] / x.shape[-1]
+    var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
     y = (x32 - mu) * jax.lax.rsqrt(var + eps)
     return y.astype(dt) * p["scale"] + p["bias"]
 
@@ -231,6 +238,9 @@ def conv2d(w: jnp.ndarray, x: jnp.ndarray, stride: int = 1,
     """x: NHWC, w: HWIO, with I = Cin / groups (``groups = Cin``: a
     depthwise conv, w of shape (kh, kw, 1, Cin)).
 
+    A depthwise conv (groups = Cin = Cout) with an odd square kernel,
+    stride 1 and padding k // 2 is ``depthwise_conv``.
+
     A strided conv whose input has so few channels that stride² · Cin
     still fits the matrix unit's contraction (ResNet's 7×7/2 stem on 3
     channels) runs as a stride-1 conv over the space-to-depth input: a
@@ -238,10 +248,13 @@ def conv2d(w: jnp.ndarray, x: jnp.ndarray, stride: int = 1,
     takes zero taps in front, so that the leading padding is whole
     space-to-depth pixels the conv pads itself, and behind, up to a
     multiple of the stride; the map is relaid out once, unpadded.  Same
-    products; the zero taps add exact zeros.  Grouped convs keep the
-    plain form."""
-    kh, kw, cin, _ = w.shape
+    products; the zero taps add exact zeros.  Other grouped convs keep
+    the plain form."""
+    kh, kw, cin, cout = w.shape
     s, p = stride, padding
+    if (groups == x.shape[-1] == cout and cin == 1 and s == 1 and kh == kw
+            and kh % 2 and p == kh // 2):
+        return depthwise_conv(w, x)[0]
     dims = ("NHWC", "HWIO", "NHWC")
     if s == 1 or s * s * cin > MXU_DEPTH or groups != 1:
         return jax.lax.conv_general_dilated(
@@ -261,6 +274,37 @@ def conv2d(w: jnp.ndarray, x: jnp.ndarray, stride: int = 1,
         xs, ws, window_strides=(1, 1),
         padding=[(lead, hs - xs.shape[1]), (lead, wd - xs.shape[2])],
         dimension_numbers=dims)
+
+
+def depthwise_conv(w: jnp.ndarray, x: jnp.ndarray,
+                   bias: jnp.ndarray | None = None
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The depthwise conv of x (NHWC) with w (k, k, 1, C), k odd, stride
+    1, padding k // 2, plus ``bias``; and each output pixel's sum over
+    channels in float32 (N, H, W), a LayerNorm's mean, which XLA would
+    otherwise fuse into its own conv.
+
+    A map larger than the kernel window runs as the ``depthwise_conv``
+    Pallas kernel: a band of rows and its halo held in VMEM, float32 sums
+    started from the bias; compiled where the program is lowered for a
+    TPU, interpreted elsewhere.  A map no larger than the window
+    (ConvNeXt-T's 7×7 stage 4) keeps XLA's conv, which a v5e runs faster
+    there: 43% of such a map's window taps fall on padding."""
+    k, c = w.shape[0], w.shape[-1]
+    b = jnp.zeros((c,), x.dtype) if bias is None else bias
+    if x.shape[1] > k and x.shape[2] > k:
+        # imported here: Pallas takes seconds to import, which a model
+        # without depthwise convs (ResNet18) should not pay at start-up
+        from repro.kernels.depthwise_conv import depthwise_conv_kernel
+        return jax.lax.platform_dependent(
+            x, w, b,
+            tpu=functools.partial(depthwise_conv_kernel, interpret=False),
+            default=functools.partial(depthwise_conv_kernel, interpret=True))
+    y = jax.lax.conv_general_dilated(
+        x, w, window_strides=(1, 1), padding=[(k // 2, k // 2)] * 2,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=c) + b
+    return y, jnp.sum(y.astype(jnp.float32), axis=-1)
 
 
 def init_bn(cout: int, dtype) -> Params:

@@ -1,6 +1,7 @@
 """Unit/property tests for core layers: RoPE, norms, masks, attention."""
 
 import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -157,38 +158,84 @@ def test_conv2d_strided_matches_lax_and_takes_space_to_depth_by_shape(
     assert convs[0].params["window_strides"] == (step, step)
 
 
-@pytest.mark.parametrize("hw, k, c", [(14, 7, 384), (7, 7, 768), (9, 3, 5)])
-def test_conv2d_depthwise_matches_shifted_sum(hw, k, c):
+@pytest.mark.parametrize("n, hw, k, c", [
+    (2, 56, 7, 96), (2, 28, 7, 192), (2, 14, 7, 384),   # ConvNeXt-T stages
+    (2, 7, 7, 768),     # stage 4: no larger than the window, XLA's conv
+    (2, 9, 3, 5),
+    (128, 9, 7, 16),    # the batch fills the lanes: the kernel sums channels
+], ids=["stage1", "stage2", "stage3", "stage4", "9x9-k3-c5", "b128-c16"])
+def test_conv2d_depthwise_matches_shifted_sum(n, hw, k, c):
     """``groups = C``: each channel convolved with its own k×k kernel, a
-    sum of k·k shifted elementwise products."""
-    x = jax.random.normal(KEY, (2, hw, hw, c))
+    sum of k·k shifted elementwise products; ``depthwise_conv`` adds the
+    bias and gives its output's sum over channels."""
+    x = jax.random.normal(KEY, (n, hw, hw, c))
     w = jax.random.normal(jax.random.fold_in(KEY, 1), (k, k, 1, c))
+    b = jax.random.normal(jax.random.fold_in(KEY, 2), (c,))
     p = k // 2
     xp = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)))
     ref = sum(xp[:, i:i + hw, j:j + hw] * w[i, j, 0]
               for i in range(k) for j in range(k))
     with jax.default_matmul_precision("highest"):
         y = L.conv2d(w, x, 1, p, groups=c)
-    assert y.shape == ref.shape
-    assert float(jnp.max(jnp.abs(y - ref)) / jnp.max(jnp.abs(ref))) <= 1e-6
+        yb, total = L.depthwise_conv(w, x, b)
+
+    def err(a, r):
+        return float(jnp.max(jnp.abs(a - r)) / jnp.max(jnp.abs(r)))
+    assert y.shape == yb.shape == ref.shape
+    assert err(y, ref) <= 1e-6
+    assert err(yb, ref + b) <= 1e-6
+    assert err(total, (ref + b).sum(-1)) <= 1e-6
+
+
+@pytest.mark.parametrize("n", [128, 1])
+def test_depthwise_tile_plan_covers_rows_once(n):
+    """At ConvNeXt-T's stage shapes the bands cover every output row once,
+    their windows at most the k − 1 halo rows more, and the input is
+    read from HBM at most twice."""
+    from repro.kernels.depthwise_conv import tile_plan
+    for hw, c in [(56, 96), (28, 192), (14, 384), (7, 768)]:
+        plan = tile_plan(n, hw, hw, c, 7)
+        bands = [range(t, t + plan.rows) for t in range(0, hw, plan.rows)]
+        assert sorted(r for band in bands for r in band) == list(range(hw))
+        read = sum(len(range(max(band.start - 3, 0), min(band.stop + 3, hw)))
+                   for band in bands)
+        assert plan.read_factor == read / hw <= 2
+        assert plan.batch_in_lanes == (n == 128 and c < 384)
+
+
+def calls_kernel(eqn) -> bool:
+    """Whether the equation, or one inside it, is a Pallas call."""
+    if eqn.primitive.name == "pallas_call":
+        return True
+    subs = [v for p in eqn.params.values()
+            for v in (p if isinstance(p, (tuple, list)) else (p,))
+            if isinstance(v, (jex.core.Jaxpr, jex.core.ClosedJaxpr))]
+    return any(calls_kernel(e) for sub in subs
+               for e in getattr(sub, "jaxpr", sub).eqns)
 
 
 def test_resnet18_convs_lower_ungrouped_and_convnext_depthwise():
+    """ResNet18's twenty convs are ungrouped; ConvNeXt-T's eighteen
+    depthwise convs are fifteen calls of the depthwise kernel (stages 1-3)
+    and three grouped convs (stage 4's 7x7 maps), its other convs (stem,
+    downsamples) ungrouped."""
     from repro.models import convnext, resnet
-    x = jnp.zeros((1, 32, 32, 3))
+    x = jnp.zeros((1, 224, 224, 3))
 
-    def groups(init, forward):
+    def lowered(init, forward):
         p = jax.eval_shape(init, jax.random.key(0))
         eqns = jax.make_jaxpr(forward)(p, x).eqns
-        return [e.params["feature_group_count"] for e in eqns
-                if e.primitive.name == "conv_general_dilated"]
+        groups = [e.params["feature_group_count"] for e in eqns
+                  if e.primitive.name == "conv_general_dilated"]
+        return groups, [e for e in eqns if calls_kernel(e)]
 
-    counts = groups(resnet.init_resnet18, resnet.forward)
-    assert len(counts) == 20 and set(counts) == {1}
-    counts = groups(convnext.init_convnext_tiny, convnext.forward)
+    counts, kernels = lowered(resnet.init_resnet18, resnet.forward)
+    assert len(counts) == 20 and set(counts) == {1} and not kernels
+    counts, kernels = lowered(convnext.init_convnext_tiny, convnext.forward)
     depthwise = [c for c in counts if c != 1]
-    assert len(depthwise) == sum(convnext.DEPTHS)
-    assert set(depthwise) == set(convnext.DIMS)
+    assert len(counts) - len(depthwise) == 4
+    assert depthwise == [convnext.DIMS[-1]] * convnext.DEPTHS[-1]
+    assert len(kernels) == sum(convnext.DEPTHS[:-1])
 
 
 def test_gelu_is_exact():

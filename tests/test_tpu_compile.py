@@ -12,9 +12,11 @@ check that every operation that computes (a fusion, a conv, a pooling
 window, a custom-call) carries exactly one of the model's layer scopes in
 its ``op_name``: the chip benchmark attributes device time to layers and
 fused groups by them; and that the 7x7/2 stem became a 4x4 conv over a
-space-to-depth input of 12 channels.  The ConvNeXt-T compiles check the
-same of its stage scopes, and that its blocks' ``dwconv`` and ``mlp``
-scopes reach the compiled operations.  The topology is described
+space-to-depth input of 12 channels, and that no ResNet18 conv runs the
+depthwise kernel.  The ConvNeXt-T compiles check the same of its stage
+scopes, that its blocks' ``dwconv`` and ``mlp`` scopes reach the compiled
+operations, and that its depthwise convs of stages 1-3 are calls of the
+depthwise kernel with no relayout beside them.  The topology is described
 inside a fixture, never at import: only one process at a time may load the
 TPU library, and every test worker imports this file.
 """
@@ -136,6 +138,8 @@ def test_resnet18_ops_carry_one_layer_scope(entry, batch, resnet18_hlo):
     seen = set()
     for opcode, target, op_name in entry_instructions(
             resnet18_hlo(entry, batch)):
+        # no ResNet18 conv is grouped: none runs the depthwise kernel
+        assert "/depthwise_conv/" not in op_name, op_name
         # XLA's reassembly of weight slices prefetched into VMEM
         if opcode not in SCOPED or target == "ConcatBitcast":
             continue
@@ -172,8 +176,10 @@ def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
         batch, one_chip, no_persistent_cache):
     """ConvNeXt-T at 224x224, float32 at ``highest``: every operation that
     computes carries exactly one stage-level scope, and the block's
-    ``dwconv`` and ``mlp`` scopes reach the compiled operations, with the
-    depthwise convs (``feature_group_count``) under ``dwconv``."""
+    ``dwconv`` and ``mlp`` scopes reach the compiled operations.  Each
+    depthwise conv is under ``dwconv``: one call of the depthwise kernel
+    in each block of stages 1-3, XLA's grouped conv on stage 4's 7x7
+    maps; and no relayout (``copy``, ``transpose``) is in that scope."""
     def spec(s):
         return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
 
@@ -195,11 +201,19 @@ def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
         seen.update(layers)
         kinds.update(c for c in path if c in ("dwconv", "mlp"))
     assert seen == CONVNEXT_LAYERS and kinds == {"dwconv", "mlp"}
+    kernels = [op_name for opcode, target, op_name in entry_instructions(hlo)
+               if target == "tpu_custom_call"]
+    assert len(kernels) == sum(convnext.DEPTHS[:-1])
+    for op_name in kernels:
+        assert "/dwconv/" in op_name and "/depthwise_conv/" in op_name
     depthwise = re.findall(r" convolution\(.*feature_group_count=(\d+).*"
                            r'op_name="([^"]*)"', hlo)
-    assert len(depthwise) >= sum(convnext.DEPTHS)
+    assert len(depthwise) == convnext.DEPTHS[-1]
     for groups, op_name in depthwise:
-        assert int(groups) in convnext.DIMS and "/dwconv/" in op_name
+        assert int(groups) == convnext.DIMS[-1] and "/stage4/dwconv/" in op_name
+    relayouts = [op_name for opcode, _, op_name in entry_instructions(hlo)
+                 if opcode in ("copy", "transpose") and "/dwconv/" in op_name]
+    assert not relayouts, relayouts
 
 
 def entry_instructions(hlo: str) -> list[tuple[str, str, str]]:
