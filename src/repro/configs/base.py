@@ -12,7 +12,7 @@ import dataclasses
 import importlib
 from typing import Literal
 
-Family = Literal["dense", "moe", "hybrid", "ssm", "audio", "vlm", "cnn"]
+Family = Literal["dense", "moe", "hybrid", "ssm", "audio", "vlm"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,8 +171,6 @@ ARCH_REGISTRY = [
 
 _MODULE_FOR = {name: "repro.configs." + name.replace("-", "_").replace(".", "_")
                for name in ARCH_REGISTRY}
-_MODULE_FOR["resnet18"] = "repro.configs.resnet18"
-_MODULE_FOR["convnext-tiny"] = "repro.configs.convnext_tiny"
 
 
 def get_config(name: str, smoke: bool = False) -> ModelConfig:

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+from typing import NamedTuple
 
 
 class OpKind(enum.Enum):
@@ -286,6 +287,40 @@ def build_resnet18(input_hw: int = 224, num_classes: int = 1000) -> Graph:
                    kh=hw, kw=hw, stride=hw, padding=0))
     L.append(Layer("fc", OpKind.FC, 512, num_classes, 1, 1, 1, 1))
     return Graph("resnet18", L)
+
+
+class ConvGeometry(NamedTuple):
+    """One ResNet18 conv as the fused CONV_BN[+ADD][+RELU] op sees it."""
+    hw: int            # input height = width
+    cin: int
+    cout: int
+    k: int
+    stride: int
+    padding: int
+    relu: bool
+    residual: bool     # ADD of the block's shortcut before the ReLU
+
+    @property
+    def name(self) -> str:
+        flags = "+ADD_RELU" if self.residual else "+RELU" if self.relu else ""
+        return (f"{self.k}x{self.k}/{self.stride} "
+                f"{self.hw}x{self.cin}->{self.cout}{flags}")
+
+
+def conv_geometries(image: int = 224) -> list[ConvGeometry]:
+    """The distinct conv geometries of ResNet18 on image×image inputs, in
+    network order, read off ``build_resnet18``: each conv layer, with a
+    block's conv2 fused with the ADD_RELU that consumes it."""
+    layers = build_resnet18(image).layers
+    fused_add = {lyr.input_of for lyr in layers
+                 if lyr.kind is OpKind.ADD_RELU}
+    geoms = [ConvGeometry(lyr.iy, lyr.cin, lyr.cout, lyr.kh, lyr.stride,
+                          lyr.padding,
+                          relu=(lyr.kind is OpKind.CONV_BN_RELU
+                                or lyr.name in fused_add),
+                          residual=lyr.name in fused_add)
+             for lyr in layers if lyr.kind.is_conv]
+    return list(dict.fromkeys(geoms))
 
 
 def first_n_layers(g: Graph, n: int) -> Graph:

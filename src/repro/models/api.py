@@ -16,7 +16,6 @@ serving path against a pre-allocated KV/state cache.
 from __future__ import annotations
 
 import dataclasses
-import importlib
 import math
 from typing import Any, Callable
 
@@ -449,34 +448,7 @@ def _build_encdec(cfg: ModelConfig) -> Model:
 # entry point
 # ---------------------------------------------------------------------------
 
-# image classifiers by config name: (module of repro.models, init function)
-CNNS = {"resnet18": ("resnet", "init_resnet18"),
-        "convnext-tiny": ("convnext", "init_convnext_tiny")}
-
-
-def _build_cnn(cfg: ModelConfig) -> Model:
-    """An image classifier: ``vocab_size`` classes, no decode path."""
-    module, init_name = CNNS[cfg.name]
-    net = importlib.import_module(f"repro.models.{module}")
-    init_net = getattr(net, init_name)
-    dtype = jnp.dtype(cfg.param_dtype)
-
-    def init(key):
-        return init_net(key, cfg.vocab_size, dtype)
-
-    def fwd(params, batch, *, remat: bool = False,
-            return_hidden: bool = False):
-        return net.forward(params, batch["images"]), jnp.float32(0.0)
-
-    def no_cache(*a, **k):
-        raise NotImplementedError("CNN classifier has no decode path")
-
-    return Model(cfg, init, fwd, no_cache, no_cache)
-
-
 def build_model(cfg: ModelConfig) -> Model:
-    if cfg.family == "cnn":
-        return _build_cnn(cfg)
     if cfg.is_encoder_decoder:
         return _build_encdec(cfg)
     if cfg.family == "hybrid":

@@ -1,28 +1,24 @@
 """ResNet18 in pure JAX (NHWC) — the paper's benchmark CNN (§V).
 
-Two execution paths:
-* ``forward`` — monolithic reference;
-* ``forward_fused_groups`` — executes the paper's fused-layer grouping
-  (stem+stage1 / stage2 / stage3 fused; stage4 + head layer-by-layer),
-  structured so each fused group is a single fusable region (consumed by
-  the Pallas fused-conv kernel and the halo-sharded distribution path).
+``init_resnet18`` draws the parameter pytree and ``forward`` maps a batch
+of images to logits.  ``forward_fused_groups`` only calls ``forward``: it
+is the name the chip benchmark's Fused4 configuration gives as its entry.
 
-Both paths run the same layer functions, each under a ``jax.named_scope``
-named as the configurations' ``groups`` name their parts: ``stem``,
-``maxpool``, ``stage1`` … ``stage4``, ``head``.  The scopes are siblings,
-never nested in one another, so XLA carries exactly one layer name into
-each operation's ``op_name`` metadata; a profiler trace is attributed to
-layers by it.  Scopes are metadata only: they change no operation.
+Each layer function runs under a ``jax.named_scope`` named as the
+configurations' ``groups`` name their parts: ``stem``, ``maxpool``,
+``stage1`` … ``stage4``, ``head``.  The scopes are siblings, never nested
+in one another, so XLA carries exactly one layer name into each
+operation's ``op_name`` metadata; a profiler trace is attributed to layers
+by it.  Scopes are metadata only: they change no operation.
 """
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.graph import OpKind, build_resnet18
 from repro.models import layers as L
 
 Params = dict[str, Any]
@@ -107,56 +103,11 @@ def forward(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     return head(p, h)
 
 
-# --- fused-group structure (paper's Fused4 grouping) ---
-
-def fused_group_fns(p: Params):
-    """The three fused groups + the layer-by-layer tail, as callables.
-    Group boundaries follow plan_fused(graph, 2, 2): [stem+stage1, stage2,
-    stage3], tail = stage4 + head."""
-    return [
-        lambda x: stage(p, stem(p, x), 0),
-        lambda x: stage(p, x, 1),
-        lambda x: stage(p, x, 2),
-    ], lambda x: head(p, stage(p, x, 3))
-
-
 def forward_fused_groups(p: Params, x: jnp.ndarray) -> jnp.ndarray:
-    groups, tail = fused_group_fns(p)
-    for g in groups:
-        x = g(x)
-    return tail(x)
-
-
-class ConvGeometry(NamedTuple):
-    """One ResNet18 conv as the fused CONV_BN[+ADD][+RELU] op sees it."""
-    hw: int            # input height = width
-    cin: int
-    cout: int
-    k: int
-    stride: int
-    padding: int
-    relu: bool
-    residual: bool     # ADD of the block's shortcut before the ReLU
-
-    @property
-    def name(self) -> str:
-        flags = "+ADD_RELU" if self.residual else "+RELU" if self.relu else ""
-        return (f"{self.k}x{self.k}/{self.stride} "
-                f"{self.hw}x{self.cin}->{self.cout}{flags}")
-
-
-def conv_geometries(image: int = 224) -> list[ConvGeometry]:
-    """The distinct conv geometries of ResNet18 on image×image inputs, in
-    network order, read off ``core.graph.build_resnet18``: each conv layer,
-    with a block's conv2 fused with the ADD_RELU that consumes it."""
-    layers = build_resnet18(image).layers
-    fused_add = {lyr.input_of for lyr in layers
-                 if lyr.kind is OpKind.ADD_RELU}
-    geoms = [ConvGeometry(lyr.iy, lyr.cin, lyr.cout, lyr.kh, lyr.stride,
-                          lyr.padding,
-                          relu=(lyr.kind is OpKind.CONV_BN_RELU
-                                or lyr.name in fused_add),
-                          residual=lyr.name in fused_add)
-             for lyr in layers if lyr.kind.is_conv]
-    return list(dict.fromkeys(geoms))
-
+    """``forward`` under the name the Fused4 configuration gives as its
+    entry.  On XLA a Python group boundary is no program boundary, so a
+    grouped forward compiles to ``forward``'s program.  The Fused4 grouping
+    lives in ``core.fusion.plan_fused`` for the PIM model and in the chip
+    benchmark configuration's ``groups`` for the trace readers; a kernel
+    that keeps a group's tile in VMEM will take a ``FusionPlan``."""
+    return forward(p, x)

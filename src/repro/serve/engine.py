@@ -1,16 +1,13 @@
-"""Batched serving engine: continuous-batching-style decode over a shared
-KV/state cache.
+"""Lock-step batched greedy decode over a shared KV/state cache.
 
-The engine keeps a fixed-capacity batch of SLOTS; requests occupy a slot,
-decode greedily until EOS or max-new-tokens, then release the slot for the
-next queued request.  Under the mesh policies the cache is sharded (batch →
-data axes, heads/sequence → model), so the same engine drives the
-decode_32k / long_500k dry-run cells.
+``ServeEngine.run_lockstep`` feeds a batch of equal-length prompts through
+the model's jitted ``decode_step`` one position per call, then decodes
+greedily for a fixed number of tokens.  Every slot advances together; a
+batch narrower than the engine's slots is padded with token 0.
 """
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Any
 
 import jax
@@ -20,16 +17,6 @@ import numpy as np
 from repro.models.api import Model
 
 
-@dataclasses.dataclass
-class Request:
-    uid: int
-    prompt: list[int]
-    max_new_tokens: int = 16
-    eos_id: int = -1             # -1: never stops early
-    output: list[int] = dataclasses.field(default_factory=list)
-    done: bool = False
-
-
 class ServeEngine:
     def __init__(self, model: Model, params: Any, *, batch_slots: int,
                  max_len: int):
@@ -37,58 +24,34 @@ class ServeEngine:
         self.params = params
         self.slots = batch_slots
         self.max_len = max_len
-        self.cache = model.init_cache(batch_slots, max_len)
-        self._decode = jax.jit(model.decode_step,
-                               static_argnames=())
-        self._active: list[Request | None] = [None] * batch_slots
-        self._queue: list[Request] = []
-        self._pos = np.zeros(batch_slots, np.int32)
+        self._decode = jax.jit(model.decode_step)
 
-    # ------------------------------------------------------------------
-    def submit(self, req: Request) -> None:
-        self._queue.append(req)
-
-    def _admit(self) -> None:
-        for i in range(self.slots):
-            if self._active[i] is None and self._queue:
-                req = self._queue.pop(0)
-                self._active[i] = req
-                self._pos[i] = 0
-                # prefill by stepping through the prompt tokens
-                for t, tok in enumerate(req.prompt):
-                    self._step_slot_token(tok)
-                    # (single shared index — engine is lock-step; prompts
-                    # are replayed per admission in this reference engine)
-
-    def _step_slot_token(self, tok: int) -> None:
-        pass  # placeholder: lock-step engine prefill folds into run()
-
-    # ------------------------------------------------------------------
     def run_lockstep(self, prompts: list[list[int]], max_new: int
                      ) -> list[list[int]]:
-        """Reference lock-step batch decode: all prompts the same length.
-        Returns generated token lists."""
+        """Decode ``max_new`` tokens after each prompt; all prompts the
+        same length.  Returns the generated token lists.
+
+        Every token array handed to the step is a fresh one that nothing
+        writes afterwards: on the CPU, ``jnp.asarray`` of a 64-byte-aligned
+        numpy buffer aliases it, and the step runs asynchronously, so a
+        host buffer refilled between steps can reach a step with the next
+        step's tokens."""
         B = len(prompts)
         assert B <= self.slots
         plen = len(prompts[0])
         assert all(len(p) == plen for p in prompts), "lock-step needs equal"
-        toks = np.zeros((self.slots, 1), np.int32)
-        outs: list[list[int]] = [[] for _ in range(B)]
+        toks = jnp.zeros((self.slots, plen), jnp.int32).at[:B].set(
+            jnp.asarray(prompts, jnp.int32))
 
         cache = self.model.init_cache(self.slots, self.max_len)
-        # prefill
         for t in range(plen):
-            for b in range(B):
-                toks[b, 0] = prompts[b][t]
             logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(toks), t)
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        # decode
+                                         toks[:, t:t + 1], t)
+        outs = jnp.zeros((self.slots, max_new), jnp.int32)
         for s in range(max_new):
-            for b in range(B):
-                outs[b].append(int(nxt[b]))
-            toks[:, 0] = nxt
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(toks), plen + s)
-            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        return outs
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            outs = outs.at[:, s].set(nxt)
+            if s + 1 < max_new:
+                logits, cache = self._decode(self.params, cache,
+                                             nxt[:, None], plen + s)
+        return np.asarray(outs)[:B].tolist()

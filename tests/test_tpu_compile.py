@@ -29,10 +29,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.graph import conv_geometries
 from repro.kernels.fused_conv import fused_conv_kernel
 from repro.models import convnext
-from repro.models.resnet import (conv_geometries, forward,
-                                 forward_fused_groups, init_resnet18)
+from repro.models.resnet import forward, forward_fused_groups, init_resnet18
 
 BATCH = 128
 LAYERS = {"stem", "maxpool", "stage1", "stage2", "stage3", "stage4", "head"}
