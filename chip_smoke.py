@@ -9,9 +9,12 @@ One chip: ResNet18 at full width (a batch of 128 224×224×3 float32
 images, 1000 classes) through ``forward``, against the same program at
 ``"highest"`` matmul precision, then the compiled ``fused_conv`` Pallas
 kernel at every conv geometry of ResNet18 (``core.graph.conv_geometries``),
-each against ``kernels.ref.fused_conv_ref``.  Four chips: the
-row-sharded fused-group halo exchange (``core.halo``) on ResNet18 stage-1
-maps against the same group on one device, and nothing else.
+each against ``kernels.ref.fused_conv_ref``, then the compiled block-MLP
+kernel (``kernels.convnext_mlp``) at each ConvNeXt-T stage whose block
+runs it at a batch of 128, against the block's XLA MLP at ``"highest"``.
+Four chips: the row-sharded fused-group halo exchange (``core.halo``) on
+ResNet18 stage-1 maps against the same group on one device, and nothing
+else.
 
 Exits non-zero without a JSON line when JAX finds no TPU or any check
 fails.  On success the last stdout line is
@@ -37,11 +40,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core.graph import ConvGeometry, conv_geometries  # noqa: E402
 from repro.core.halo import run_fused_group, run_fused_group_exact  # noqa: E402
+from repro.kernels.convnext_mlp import convnext_mlp_kernel  # noqa: E402
+from repro.kernels.depthwise_conv import batch_in_lanes  # noqa: E402
 from repro.kernels.fused_conv import fused_conv_kernel  # noqa: E402
 from repro.kernels.ref import fused_conv_ref  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.models import layers as L  # noqa: E402
+from repro.models import convnext, layers as L  # noqa: E402
 from repro.models.resnet import forward, init_resnet18, stage  # noqa: E402
 
 # Tolerances on max|out - ref| / max|ref|.
@@ -131,6 +136,37 @@ def fused_conv_geometry(ck: Checks, key, batch: int,
              f"shape {out.shape}, rel err {e:.3e} <= {TOL_KERNEL:g}")
 
 
+def convnext_mlp_stage(ck: Checks, key, batch: int, hw: int, c: int) -> None:
+    """The block-MLP kernel on one stage's maps, every parameter drawn,
+    against ``convnext.block``'s XLA MLP and residual add."""
+    ks = jax.random.split(key, 9)
+    d = convnext.MLP_RATIO * c
+    h = jax.random.normal(ks[0], (batch, hw, hw, c)) * 2 + 0.3
+    x = jax.random.normal(ks[1], (batch, hw, hw, c))
+    ln = {"scale": jax.random.uniform(ks[2], (c,), minval=0.5, maxval=1.5),
+          "bias": jax.random.normal(ks[3], (c,)) * 0.2}
+    w1 = jax.random.normal(ks[4], (c, d)) / c ** 0.5
+    b1 = jax.random.normal(ks[5], (d,)) * 0.2
+    w2 = jax.random.normal(ks[6], (d, c)) / d ** 0.5
+    b2 = jax.random.normal(ks[7], (c,)) * 0.2
+    gamma = jax.random.uniform(ks[8], (c,), minval=0.1, maxval=1.0)
+    args = (h, jnp.sum(h, axis=-1), x, ln["scale"], ln["bias"], w1, b1, w2,
+            b2, gamma)
+    eps = convnext.LN_EPS
+
+    def ref(h, total, x, scale, bias, w1, b1, w2, b2, gamma):
+        t = L.layernorm({"scale": scale, "bias": bias}, h, eps, total)
+        return x + (L.gelu(t @ w1 + b1) @ w2 + b2) * gamma
+
+    out = compile_and_run(lambda *a: convnext_mlp_kernel(*a, eps=eps), *args)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(ref)(*args)
+    e = rel_err(out, want)
+    ck.check(f"convnext_mlp {hw}x{hw}x{c}",
+             out.shape == want.shape and e <= TOL_KERNEL,
+             f"shape {out.shape}, rel err {e:.3e} <= {TOL_KERNEL:g}")
+
+
 def halo_phase(ck: Checks, key, batch: int, devices) -> None:
     """core.halo on a 4-way row-sharded ResNet18 stage-1 map (56×56×64)."""
     n = 4
@@ -214,6 +250,12 @@ def main() -> int:
         for i, g in enumerate(conv_geometries(IMAGE)):
             ck.run(f"fused_conv {g.name}", fused_conv_geometry,
                    jax.random.fold_in(key, 100 + i), BATCH, g)
+        hw = IMAGE // convnext.PATCH
+        for i, c in enumerate(convnext.DIMS):
+            if batch_in_lanes(BATCH, c):
+                ck.run(f"convnext_mlp {c}", convnext_mlp_stage,
+                       jax.random.fold_in(key, 200 + i), BATCH, hw, c)
+            hw //= 2
     print(f"  (info) compile cache: {cache.hits} hits, {cache.misses} misses",
           flush=True)
     if ck.failed:

@@ -57,6 +57,14 @@ class TilePlan(NamedTuple):
     read_factor: float      # input rows DMA'd from HBM ÷ rows of the map
 
 
+def batch_in_lanes(n: int, c: int) -> bool:
+    """Whether an (n, ·, ·, c) map pads its (sublane, lane) tile less with
+    the batch in lanes than with the channels (channels on a tie): the
+    layout XLA keeps ConvNeXt-T's stage 1-2 maps in at a batch of 128."""
+    return (_round_up(c, SUBLANES) * _round_up(n, LANES)
+            < _round_up(n, SUBLANES) * _round_up(c, LANES))
+
+
 def tile_plan(n: int, h: int, w: int, c: int, k: int) -> TilePlan:
     """The kernel's tiling of an (n, h, w, c) map for a k×k depthwise conv:
     the (sublane, lane) order of batch and channels that pads the tile
@@ -64,19 +72,18 @@ def tile_plan(n: int, h: int, w: int, c: int, k: int) -> TilePlan:
     allows, 128 lanes, and the most rows per band whose buffers fit
     ``VMEM_BUDGET``.  A band has at least k // 2 rows, unless it is the
     whole map, so only the first and last band reach past it."""
-    batch_in_lanes = (_round_up(c, SUBLANES) * _round_up(n, LANES)
-                      < _round_up(n, SUBLANES) * _round_up(c, LANES))
-    sub = c if batch_in_lanes else n
+    lanes_batch = batch_in_lanes(n, c)
+    sub = c if lanes_batch else n
     block = SUBLANES if sub % SUBLANES == 0 else sub
     tile = _round_up(block, SUBLANES) * LANES * 4
-    cb, nb = (block, LANES) if batch_in_lanes else (LANES, block)
+    cb, nb = (block, LANES) if lanes_batch else (LANES, block)
     halo = k - 1
     fits = [d for d in range(1, h + 1)
             if h % d == 0 and (d == h or d >= k // 2)]
     rows = max([d for d in fits
                 if 2 * ((d + halo) * (w + halo) + d * w) * tile
                 <= VMEM_BUDGET], default=min(fits))
-    return TilePlan(rows, batch_in_lanes, cb, nb,
+    return TilePlan(rows, lanes_batch, cb, nb,
                     (h + halo * (h // rows - 1)) / h)
 
 

@@ -19,7 +19,11 @@ so each operation carries exactly one of them.  Inside each block two
 nested scopes split a trace by kind: ``dwconv`` (``layers.depthwise_conv``:
 the depthwise conv, its bias, and each pixel's sum over channels, which
 the LayerNorm takes for its mean) and ``mlp`` (LayerNorm, expansion,
-GELU, projection, layer scale).  The residual add is in neither.
+GELU, projection, layer scale).  Where the maps keep the batch in lanes
+(stages 1-2 at a batch of 128) the MLP and the residual add are one call
+of the ``convnext_mlp`` Pallas kernel under ``mlp``, which keeps the
+4d-wide hidden map out of HBM; elsewhere they are XLA's ops and the
+residual add is in neither scope.
 
 ``forward`` reads every size from the parameters, so the same function
 runs any depth and width of the family.
@@ -27,11 +31,14 @@ runs any depth and width of the family.
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.convnext_mlp import convnext_mlp_kernel
+from repro.kernels.depthwise_conv import batch_in_lanes
 from repro.models import layers as L
 
 Params = dict[str, Any]
@@ -88,10 +95,27 @@ def block(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     with jax.named_scope("dwconv"):
         h, total = L.depthwise_conv(p["dw_w"], x, p["dw_b"])
     with jax.named_scope("mlp"):
+        # maps with the batch in lanes (stages 1-2 at a batch of 128): the
+        # kernel, which a v5e ran faster than XLA's expansion and
+        # projection there; with the channels in lanes its layout would
+        # cost a relayout each way
+        if batch_in_lanes(x.shape[0], x.shape[-1]):
+            return _mlp_kernel(p, h, total, x)
         h = L.layernorm(p["ln"], h, LN_EPS, total)
         h = L.gelu(h @ p["w1"] + p["b1"])
         h = (h @ p["w2"] + p["b2"]) * p["gamma"]
     return x + h
+
+
+def _mlp_kernel(p: Params, h, total, x) -> jnp.ndarray:
+    """The block's MLP and residual add as one call of the block-MLP
+    kernel: compiled where the program is lowered for a TPU, interpreted
+    elsewhere."""
+    kernel = functools.partial(convnext_mlp_kernel, eps=LN_EPS)
+    return jax.lax.platform_dependent(
+        h, total, x, p["ln"]["scale"], p["ln"]["bias"], p["w1"], p["b1"],
+        p["w2"], p["b2"], p["gamma"],
+        tpu=kernel, default=functools.partial(kernel, interpret=True))
 
 
 def stem(p: Params, x: jnp.ndarray) -> jnp.ndarray:

@@ -246,6 +246,69 @@ def test_gelu_is_exact():
     assert float(jnp.max(jnp.abs(L.gelu(x) - jax.nn.gelu(x)))) > 1e-4
 
 
+def _ordinal(v: np.ndarray) -> np.ndarray:
+    """float32 values as integers in the order of the floats: neighbours
+    differ by one, and the gap between two values counts ulps."""
+    i = v.view(np.int32).astype(np.int64)
+    return np.where(i < 0, -(i & 0x7FFFFFFF), i)
+
+
+def test_convnext_mlp_erf_within_ulps_of_lax_erf():
+    """The block-MLP kernel writes erf as a float32 rational (Mosaic lowers
+    no erf).  Over 2^21 steps of [-6, 6], both tails from 1e-38 to 1e38,
+    ±0 and ±inf it is at most 8 ulps from ``jax.lax.erf``, keeps the sign
+    of zero, and on normal floats it is at most 6 ulps from erf in float64
+    (``jax.lax.erf``: 5); the largest gaps lie near |x| = 3.7, where erf
+    is within 1e-7 of 1."""
+    from scipy.special import erf as erf64
+
+    from repro.kernels.convnext_mlp import erf
+    tails = np.logspace(-38, 38, 20001).astype(np.float32)
+    x = np.concatenate([np.linspace(-6, 6, 2**21 + 1, dtype=np.float32),
+                        tails, -tails,
+                        np.float32([0.0, -0.0, np.inf, -np.inf])])
+    y = np.asarray(jax.jit(erf)(x))
+    lax = np.asarray(jax.lax.erf(x))
+    assert np.abs(_ordinal(y) - _ordinal(lax)).max() <= 8
+    assert list(np.signbit(y[-4:-2])) == [False, True]
+    normal = (np.abs(x) >= np.finfo(np.float32).tiny) | (x == 0)
+    exact = erf64(x[normal].astype(np.float64)).astype(np.float32)
+    assert np.abs(_ordinal(y[normal]) - _ordinal(exact)).max() <= 6
+
+
+@pytest.mark.parametrize("n, hw, c", [
+    (8, 5, 96),         # stage-1 width: 25 pixels, the last tile past the map
+    (8, 4, 192),        # stage-2 width
+    (128, 3, 96),       # a whole lane tile of batch, nothing padded
+], ids=["stage1-b8", "stage2-b8", "stage1-b128"])
+def test_convnext_mlp_kernel_matches_xla_block(n, hw, c):
+    """The block-MLP kernel (interpreted) against the block's MLP as XLA
+    runs it where the kernel does not (``layers.layernorm`` with the mean
+    from the channel sums, expansion, ``layers.gelu``, projection, layer
+    scale) and the residual add, every parameter drawn; float32 at
+    ``highest`` on both sides, so within 1e-6 of the largest output."""
+    from repro.kernels.convnext_mlp import convnext_mlp_kernel
+    ks = jax.random.split(jax.random.fold_in(KEY, c), 9)
+    d = 4 * c
+    h = jax.random.normal(ks[0], (n, hw, hw, c)) * 2 + 0.3
+    x = jax.random.normal(ks[1], (n, hw, hw, c))
+    ln = {"scale": jax.random.uniform(ks[2], (c,), minval=0.5, maxval=1.5),
+          "bias": jax.random.normal(ks[3], (c,)) * 0.2}
+    w1 = jax.random.normal(ks[4], (c, d)) / c ** 0.5
+    b1 = jax.random.normal(ks[5], (d,)) * 0.2
+    w2 = jax.random.normal(ks[6], (d, c)) / d ** 0.5
+    b2 = jax.random.normal(ks[7], (c,)) * 0.2
+    gamma = jax.random.uniform(ks[8], (c,), minval=0.1, maxval=1.0)
+    total = jnp.sum(h, axis=-1)
+    with jax.default_matmul_precision("highest"):
+        t = L.gelu(L.layernorm(ln, h, 1e-6, total) @ w1 + b1)
+        ref = x + (t @ w2 + b2) * gamma
+    y = convnext_mlp_kernel(h, total, x, ln["scale"], ln["bias"], w1, b1, w2,
+                            b2, gamma, eps=1e-6, interpret=True)
+    assert y.shape == ref.shape
+    assert float(jnp.max(jnp.abs(y - ref)) / jnp.max(jnp.abs(ref))) <= 1e-6
+
+
 def test_maxpool_basic():
     x = jnp.arange(16.0).reshape(1, 4, 4, 1)
     y = L.maxpool2d(x, 2, 2, 0)

@@ -15,10 +15,11 @@ fused groups by them; and that the 7x7/2 stem became a 4x4 conv over a
 space-to-depth input of 12 channels, and that no ResNet18 conv runs the
 depthwise kernel.  The ConvNeXt-T compiles check the same of its stage
 scopes, that its blocks' ``dwconv`` and ``mlp`` scopes reach the compiled
-operations, and that its depthwise convs of stages 1-3 are calls of the
-depthwise kernel with no relayout beside them.  The topology is described
-inside a fixture, never at import: only one process at a time may load the
-TPU library, and every test worker imports this file.
+operations, that its depthwise convs of stages 1-3 are calls of the
+depthwise kernel and, at a batch of 128, its MLPs of stages 1-2 calls of
+the block-MLP kernel, with no relayout beside either.  The topology is
+described inside a fixture, never at import: only one process at a time
+may load the TPU library, and every test worker imports this file.
 """
 
 import os
@@ -171,6 +172,10 @@ def test_resnet18_stem_conv_runs_over_space_to_depth(entry, batch,
 CONVNEXT_LAYERS = {"stem", "stage1", "stage2", "stage3", "stage4", "head"}
 
 
+def stage_of(op_name: str) -> str:
+    return next(c for c in op_name.split("/") if c in CONVNEXT_LAYERS)
+
+
 @pytest.mark.parametrize("batch", [BATCH, 1])
 def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
         batch, one_chip, no_persistent_cache):
@@ -179,7 +184,10 @@ def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
     ``dwconv`` and ``mlp`` scopes reach the compiled operations.  Each
     depthwise conv is under ``dwconv``: one call of the depthwise kernel
     in each block of stages 1-3, XLA's grouped conv on stage 4's 7x7
-    maps; and no relayout (``copy``, ``transpose``) is in that scope."""
+    maps.  Where the maps keep the batch in lanes (stages 1-2 at a batch
+    of 128, none at 1) each block's MLP is one call of the block-MLP
+    kernel under ``mlp``.  No relayout (``copy``, ``transpose``) is in a
+    ``dwconv`` scope or in an ``mlp`` scope that holds the kernel."""
     def spec(s):
         return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
 
@@ -203,16 +211,25 @@ def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
     assert seen == CONVNEXT_LAYERS and kinds == {"dwconv", "mlp"}
     kernels = [op_name for opcode, target, op_name in entry_instructions(hlo)
                if target == "tpu_custom_call"]
-    assert len(kernels) == sum(convnext.DEPTHS[:-1])
-    for op_name in kernels:
-        assert "/dwconv/" in op_name and "/depthwise_conv/" in op_name
+    dw_kernels = [k for k in kernels if "/depthwise_conv/" in k]
+    assert len(dw_kernels) == sum(convnext.DEPTHS[:-1])
+    for op_name in dw_kernels:
+        assert "/dwconv/" in op_name
+    mlps = [k for k in kernels if "/convnext_mlp/" in k]
+    assert len(dw_kernels) + len(mlps) == len(kernels)
+    fused = {"stage1", "stage2"} if batch == BATCH else set()
+    assert len(mlps) == (sum(convnext.DEPTHS[:2]) if fused else 0)
+    for op_name in mlps:
+        assert "/mlp/" in op_name and stage_of(op_name) in fused
     depthwise = re.findall(r" convolution\(.*feature_group_count=(\d+).*"
                            r'op_name="([^"]*)"', hlo)
     assert len(depthwise) == convnext.DEPTHS[-1]
     for groups, op_name in depthwise:
         assert int(groups) == convnext.DIMS[-1] and "/stage4/dwconv/" in op_name
     relayouts = [op_name for opcode, _, op_name in entry_instructions(hlo)
-                 if opcode in ("copy", "transpose") and "/dwconv/" in op_name]
+                 if opcode in ("copy", "transpose") and (
+                     "/dwconv/" in op_name or "/mlp/" in op_name
+                     and stage_of(op_name) in fused)]
     assert not relayouts, relayouts
 
 
