@@ -119,9 +119,12 @@ def _softcap(logits: jnp.ndarray, cap: float) -> jnp.ndarray:
 
 
 def attention_scores(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                     mask: jnp.ndarray, softcap: float = 0.0) -> jnp.ndarray:
+                     mask: jnp.ndarray | None, softcap: float = 0.0,
+                     bias: jnp.ndarray | None = None) -> jnp.ndarray:
     """q: (B,S,H,hd)  k/v: (B,T,KV,hd) with H = KV*G.  mask: broadcastable
-    to (B,H,S,T), True = attend."""
+    to (B,H,S,T), True = attend; ``None`` attends everywhere.  bias: a
+    float32 term (B or 1, H, S, T) added to the logits before the softmax
+    (Swin's relative position bias and shift mask)."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
@@ -129,9 +132,12 @@ def attention_scores(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     logits = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
     logits = _softcap(logits, softcap)
-    m = mask.reshape(B, KV, G, S, T) if mask.ndim == 4 and mask.shape[1] == H \
-        else mask[:, None, None, :, :] if mask.ndim == 3 else mask
-    logits = jnp.where(m, logits, -1e30)
+    if bias is not None:
+        logits = logits + bias.reshape(bias.shape[0], KV, G, S, T)
+    if mask is not None:
+        m = mask.reshape(B, KV, G, S, T) if mask.ndim == 4 and mask.shape[1] == H \
+            else mask[:, None, None, :, :] if mask.ndim == 3 else mask
+        logits = jnp.where(m, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgst,btkh->bskgh", probs, v.astype(jnp.float32))
     return out.reshape(B, S, H, hd).astype(q.dtype)

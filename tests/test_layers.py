@@ -1,4 +1,7 @@
-"""Unit/property tests for core layers: RoPE, norms, masks, attention."""
+"""Unit/property tests for core layers: RoPE, norms, masks, attention,
+and Swin's windows."""
+
+import re
 
 import jax
 import jax.extend as jex
@@ -10,6 +13,7 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.models import layers as L  # noqa: E402
+from repro.models import swin  # noqa: E402
 
 KEY = jax.random.PRNGKey(11)
 
@@ -114,6 +118,103 @@ def test_attention_scores_gqa_equivalence():
     # repeated-kv MHA maps head h to kv h//2 in GQA ordering
     np.testing.assert_allclose(np.asarray(out_gqa), np.asarray(out_mha),
                                atol=1e-5)
+
+
+@pytest.mark.parametrize("bias_shape", [(2, 3, 6, 6), (1, 3, 6, 6)])
+def test_attention_scores_adds_bias_before_the_softmax(bias_shape):
+    B, S, H, D = 2, 6, 3, 8
+    q, k, v = (jax.random.normal(jax.random.fold_in(KEY, i), (B, S, H, D))
+               for i in range(3))
+    bias = 2.0 * jax.random.normal(jax.random.fold_in(KEY, 3), bias_shape)
+    with jax.default_matmul_precision("highest"):
+        out = L.attention_scores(q, k, v, None, bias=bias)
+        logits = jnp.einsum("bshd,bthd->bhst", q, k) / np.sqrt(D) + bias
+        want = jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(logits, -1), v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+
+
+def _attention_scores_before_bias(q, k, v, mask, softcap=0.0):
+    """``attention_scores`` as it was before it took ``bias``."""
+    B, S, H, hd = q.shape
+    T, KV = k.shape[1], k.shape[2]
+    G = H // KV
+    qg = q.reshape(B, S, KV, G, hd)
+    logits = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
+                        k.astype(jnp.float32)) / np.sqrt(hd)
+    logits = L._softcap(logits, softcap)
+    m = mask.reshape(B, KV, G, S, T) if mask.ndim == 4 and mask.shape[1] == H \
+        else mask[:, None, None, :, :] if mask.ndim == 3 else mask
+    logits = jnp.where(m, logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgst,btkh->bskgh", probs, v.astype(jnp.float32))
+    return out.reshape(B, S, H, hd).astype(q.dtype)
+
+
+@pytest.mark.parametrize("per_head_mask", [False, True])
+@pytest.mark.parametrize("softcap", [0.0, 50.0])
+def test_attention_scores_without_bias_compiles_as_before(per_head_mask,
+                                                          softcap):
+    """With ``bias=None`` (every LM caller) the compiled program is the
+    one from before the argument, metadata aside."""
+    B, S, H, KV, D = 2, 8, 4, 2, 16
+    q = jnp.zeros((B, S, H, D))
+    k = v = jnp.zeros((B, S, KV, D))
+    mask = L.causal_mask(S, S)
+    if per_head_mask:
+        mask = jnp.broadcast_to(mask[:, None], (B, H, S, S))
+
+    def hlo(fn):
+        text = jax.jit(lambda q, k, v, m: fn(q, k, v, m, softcap)).lower(
+            q, k, v, mask).compile().as_text()
+        # the source-location tables and each op's metadata name the code
+        blocks = [b for b in text.split("\n\n") if b.split("\n")[0] not in
+                  ("FileNames", "FunctionNames", "FileLocations", "StackFrames")]
+        return re.sub(r", metadata=\{[^}]*\}", "", "\n\n".join(blocks))
+    assert hlo(L.attention_scores) == hlo(_attention_scores_before_bias)
+
+
+# ---------------------------------------------------------------------------
+# Swin's windows (repro.models.swin)
+# ---------------------------------------------------------------------------
+
+def test_window_partition_then_reverse_is_the_identity():
+    x = jax.random.normal(KEY, (2, 14, 21, 5))
+    xw = swin.window_partition(x, 7)
+    assert xw.shape == (2 * 2 * 3, 49, 5)
+    # the second window of the first image: rows 0-6, columns 7-13
+    np.testing.assert_array_equal(np.asarray(xw[1]).reshape(7, 7, 5),
+                                  np.asarray(x[0, :7, 7:14]))
+    np.testing.assert_array_equal(np.asarray(swin.window_reverse(xw, 7, 14, 21)),
+                                  np.asarray(x))
+
+
+def test_shift_mask_is_the_published_slice_labelled_mask():
+    """The official construction: label the map's 3x3 slice regions, cut
+    the labels into windows, and put -100 wherever two labels differ."""
+    hw, w, s = 14, 7, 3
+    img = np.zeros((1, hw, hw, 1))
+    cnt = 0
+    for hs in (slice(0, -w), slice(-w, -s), slice(-s, None)):
+        for ws in (slice(0, -w), slice(-w, -s), slice(-s, None)):
+            img[:, hs, ws, :] = cnt
+            cnt += 1
+    windows = img.reshape(1, hw // w, w, hw // w, w, 1).transpose(
+        0, 1, 3, 2, 4, 5).reshape(-1, w * w)
+    diff = windows[:, None, :] - windows[:, :, None]
+    want = np.where(diff != 0, -100.0, 0.0)
+    np.testing.assert_array_equal(swin.shift_mask(hw, w, s), want)
+    # the top-left window holds one region; the bottom-right one four
+    assert not want[0].any() and len(np.unique(windows[-1])) == 4
+
+
+def test_relative_index_is_the_offset_in_a_13_by_13_table():
+    w = 7
+    want = np.zeros((w * w, w * w), int)
+    for i in range(w * w):
+        for j in range(w * w):
+            (yi, xi), (yj, xj) = divmod(i, w), divmod(j, w)
+            want[i, j] = (yi - yj + 6) * 13 + (xi - xj + 6)
+    np.testing.assert_array_equal(swin.relative_index(w), want)
 
 
 # ---------------------------------------------------------------------------

@@ -17,9 +17,13 @@ depthwise kernel.  The ConvNeXt-T compiles check the same of its stage
 scopes, that its blocks' ``dwconv`` and ``mlp`` scopes reach the compiled
 operations, that its depthwise convs of stages 1-3 are calls of the
 depthwise kernel and, at a batch of 128, its MLPs of stages 1-2 calls of
-the block-MLP kernel, with no relayout beside either.  The topology is
-described inside a fixture, never at import: only one process at a time
-may load the TPU library, and every test worker imports this file.
+the block-MLP kernel, with no relayout beside either.  The Swin-T
+compiles check the same of its stage scopes, that its blocks' ``attn``
+and ``mlp`` scopes reach the compiled operations, and that it holds no
+gather (its bias tables are read by a one-hot product) and no kernel.
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
 """
 
 import os
@@ -32,7 +36,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.graph import conv_geometries
 from repro.kernels.fused_conv import fused_conv_kernel
-from repro.models import convnext
+from repro.models import convnext, swin
 from repro.models.resnet import forward, forward_fused_groups, init_resnet18
 
 BATCH = 128
@@ -231,6 +235,41 @@ def test_convnext_ops_carry_one_stage_scope_and_split_by_kind(
                      "/dwconv/" in op_name or "/mlp/" in op_name
                      and stage_of(op_name) in fused)]
     assert not relayouts, relayouts
+
+
+@pytest.mark.parametrize("batch", [BATCH, 1])
+def test_swin_ops_carry_one_stage_scope_and_split_by_kind(
+        batch, one_chip, no_persistent_cache):
+    """Swin-T at 224x224, float32 at ``highest``: every operation that
+    computes carries exactly one stage-level scope (each patch merging in
+    the stage it opens), and the block's ``attn`` and ``mlp`` scopes reach
+    the compiled operations.  No relative-position bias lookup or strided
+    slice became a gather, and no custom-call but XLA's reassembly of
+    prefetched weights is in the program."""
+    def spec(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(swin.init_swin_tiny,
+                                               jax.random.key(0)))
+    x = spec(jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.float32))
+
+    def call(p, x):
+        with jax.default_matmul_precision("highest"):
+            return swin.forward(p, x)
+    hlo = jax.jit(call).lower(params, x).compile().as_text()
+    seen, kinds = set(), set()
+    for opcode, target, op_name in entry_instructions(hlo):
+        assert opcode != "custom-call" or target == "ConcatBitcast", target
+        if opcode not in SCOPED or target == "ConcatBitcast":
+            continue
+        path = op_name.split("/")
+        # Swin-T's stage scopes are ConvNeXt-T's
+        layers = [c for c in path if c in CONVNEXT_LAYERS]
+        assert len(layers) == 1, (opcode, target, op_name)
+        seen.update(layers)
+        kinds.update(c for c in path if c in ("attn", "mlp"))
+    assert seen == CONVNEXT_LAYERS and kinds == {"attn", "mlp"}
+    assert " gather(" not in hlo
 
 
 def entry_instructions(hlo: str) -> list[tuple[str, str, str]]:
